@@ -7,8 +7,6 @@ Paper artifacts reproduced:
 
 System benches beyond the paper:
   * tiny-LM train-step throughput (tokens/s) per architecture family
-  * distributed halo-exchange stencil on 8 simulated devices (subprocess —
-    jax locks the device count at init, so it gets its own process)
 
 Prints ``name,us_per_call,derived`` CSV per the harness contract.
 
@@ -22,8 +20,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
 import sys
 import time
 from pathlib import Path
@@ -176,55 +172,6 @@ def bench_lm_train() -> None:
 
         us = _time(call, warmup=2, iters=5)
         row(f"train_step_{arch}_reduced", us, f"{4 * 64 / (us / 1e6):.0f}tok/s")
-
-
-# ---------------------------------------------------------------------------
-# distributed halo-exchange stencil (8 simulated devices, subprocess)
-# ---------------------------------------------------------------------------
-
-_DIST_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys, time, json
-sys.path.insert(0, {src!r})
-import repro
-import jax, jax.numpy as jnp
-import numpy as np
-from repro.stencils.hdiff import build_hdiff
-from repro.stencils.distributed import DistributedStencil
-
-mesh = jax.make_mesh((4, 2), ("data", "model"))
-st = build_hdiff("jax")
-dist = DistributedStencil(st, mesh, i_axis="data", j_axis="model")
-NI, NJ, NK = 256, 128, 16
-rng = np.random.default_rng(0)
-fields = {{
-    "in_phi": jnp.asarray(rng.normal(size=(NI, NJ, NK))),
-    "out_phi": jnp.zeros((NI, NJ, NK)),
-}}
-scalars = {{"alpha": np.float64(0.05)}}
-out = dist(fields, scalars)  # compile
-jax.block_until_ready(out["out_phi"])
-t0 = time.perf_counter()
-for _ in range(10):
-    out = dist(fields, scalars)
-jax.block_until_ready(out["out_phi"])
-us = (time.perf_counter() - t0) / 10 * 1e6
-print(json.dumps({{"us": us, "devices": 8}}))
-"""
-
-
-def bench_distributed_stencil() -> None:
-    script = _DIST_SCRIPT.format(src=SRC)
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    try:
-        res = subprocess.run([sys.executable, "-c", script],
-                             capture_output=True, text=True, timeout=600, env=env)
-        line = res.stdout.strip().splitlines()[-1]
-        data = json.loads(line)
-        row("hdiff_distributed_8dev_256x128x16", data["us"], "halo-exchange shard_map")
-    except Exception as e:  # noqa: BLE001
-        row("hdiff_distributed_8dev_256x128x16", float("nan"), f"failed: {e}")
 
 
 # ---------------------------------------------------------------------------
@@ -871,7 +818,6 @@ def main() -> None:
     bench_vadv()
     bench_call_overhead()
     bench_lm_train()
-    bench_distributed_stencil()
     out = Path(__file__).resolve().parent.parent / "experiments"
     out.mkdir(exist_ok=True)
     (out / "bench_results.csv").write_text(

@@ -60,8 +60,11 @@ DEFAULT_NUMPY_CANDIDATES: Tuple[Tuple[int, int], ...] = (
 def _is_numpy_module(module) -> bool:
     return getattr(module, "_BACKEND", None) == "numpy"
 
-# don't time tiles whose estimated footprint exceeds ~3/4 of a 16 MB VMEM core
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# Mosaic's default scoped-VMEM limit per kernel (16 MiB on a v5e).  The
+# generated ``_vmem_bytes`` estimates from above what the compiler allocates
+# (checked against it in tests/test_tpu_compile.py), so a tile under this
+# budget compiles.
+VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 _lock = threading.Lock()
 _memory: Dict[Tuple[str, str, str], Dict[str, Any]] = {}

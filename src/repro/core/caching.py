@@ -25,6 +25,29 @@ _lock = threading.Lock()
 _memory_cache: Dict[str, ModuleType] = {}
 
 
+#: the checkout this package runs from (``src/repro/core`` is three levels in)
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself, and
+    nothing is set here).  Otherwise the cache is ``.jax_cache`` in the
+    checkout, a fixed path: the path is part of what a later run must find
+    again.  Entry points that drive the chip call this
+    (``chip_smoke.py``, ``repro.launch.serve``); importing ``repro`` never
+    does, so tests run without a compile cache."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def cache_dir() -> Path:
     root = os.environ.get("REPRO_GT_CACHE")
     if root:

@@ -76,9 +76,15 @@ class ArrayExprPrinter:
         lib: str,  # 'np' | 'jnp'
         axes_of: Dict[str, Tuple[str, ...]],
         dtype_of: Dict[str, str],
+        mosaic: bool = False,
     ):
         self.impl = impl
         self.lib = lib
+        # Pallas TPU kernels (codegen_pallas): Mosaic lowers no dynamic_slice
+        # or dynamic_update_slice, so writes go through ``_put`` (plain
+        # assignment or an iota-mask select) and plane reads at the traced
+        # sweep level through ``_kget`` (a masked lane reduction).
+        self.mosaic = mosaic
         self.axes_of = axes_of
         self.dtype_of = dtype_of
         self.mode = "block"
@@ -101,6 +107,9 @@ class ArrayExprPrinter:
         # trailing reads hit the rolling history ``_wh_<name>_<q>`` instead of
         # a full 3-D array.  Bound by emit_sweep for the active multi-stage.
         self.window: Dict[str, int] = {}
+        # Pallas inputs read straight from their VMEM halo scratch ref
+        # (name -> ref expression) rather than from an in-kernel value copy
+        self.refs: Dict[str, str] = {}
 
     # -- region slices ---------------------------------------------------------
 
@@ -129,6 +138,11 @@ class ArrayExprPrinter:
             return f"_ok_{name} + {self.k0}{_c(dk)}:_ok_{name} + {self.k1}{_c(dk)}"
         return f"_ok_{name} + k{_c(dk)}"
 
+    def _kplane(self, array: str, name: str, dk: int) -> str:
+        """Mosaic form of ``array`` (K last) read at the traced sweep level."""
+        self.used_helpers.add("kget")
+        return f"_kget({array}, {self._kslice(name, dk)})"
+
     def read(self, fa: ir.FieldAccess) -> str:
         name = fa.name
         if name in self.locals_:
@@ -140,18 +154,27 @@ class ArrayExprPrinter:
                 return f"_wp_{name}[{si}, {sj}]"
             return f"_wh_{name}_{abs(dk)}[{si}, {sj}]"
         axes = self.axes_of[name]
+        # a ref (Pallas VMEM scratch) is loaded window by window: no None
+        # axes in its index, and its K extent is padded past nk
+        ref = self.refs.get(name)
+        arr = ref or name
+        kall = ":nk" if ref else ":"
         if axes == ("I", "J", "K"):
             si, sj = self._hslices(name, di, dj)
-            return f"{name}[{si}, {sj}, {self._kslice(name, dk)}]"
+            if self.mode == "plane" and self.mosaic:
+                return self._kplane(f"{arr}[{si}, {sj}, {kall}]", name, dk)
+            return f"{arr}[{si}, {sj}, {self._kslice(name, dk)}]"
         if axes == ("I", "J"):
             si, sj = self._hslices(name, di, dj)
             if self.mode == "block":
-                return f"{name}[{si}, {sj}, None]"
-            return f"{name}[{si}, {sj}]"
+                return f"{arr}[{si}, {sj}][:, :, None]" if ref else f"{arr}[{si}, {sj}, None]"
+            return f"{arr}[{si}, {sj}]"
         if axes == ("K",):
             if self.mode == "block":
-                return f"{name}[None, None, {self._kslice(name, dk)}]"
-            return f"{name}[{self._kslice(name, dk)}]"
+                return f"{arr}[None, None, {self._kslice(name, dk)}]"
+            if self.mosaic:
+                return self._kplane(arr, name, dk)
+            return f"{arr}[{self._kslice(name, dk)}]"
         raise NotImplementedError(f"axes {axes}")
 
     def write_target(self, name: str) -> str:
@@ -168,7 +191,7 @@ class ArrayExprPrinter:
 
     def write_starts_shape(self, name: str) -> Tuple[str, str]:
         """(start-indices tuple expr, region shape tuple expr) for functional
-        writes via lax.dynamic_update_slice (Pallas kernels may not capture
+        writes through ``_dus`` / ``_put`` (Pallas kernels may not capture
         the scatter constants `.at[].set()` would create)."""
         axes = self.axes_of[name]
         (ilo, ihi), (jlo, jhi), _ = self.extent.as_tuple()
@@ -275,18 +298,19 @@ class ArrayStmtEmitter:
         if mask is not None:
             old = p.read(ir.FieldAccess(name, (0, 0, 0)))
             value = f"{p.lib}.where({mask}, {value}, {old})"
+        write = "put" if p.mosaic else "dus"
         if name in p.locals_:
             # demoted temporary: direct variable binding, no field write
             self.em.line(f"{name} = {value}")
         elif p.mode == "plane" and name in p.window:
             # k-blocked sweep temporary: write the current 2-D plane
-            p.used_helpers.add("dus")
+            p.used_helpers.add(write)
             starts, shape = p.plane_write_starts_shape(name)
-            self.em.line(f"_wp_{name} = _dus(_wp_{name}, {value}, {starts}, {shape})")
+            self.em.line(f"_wp_{name} = _{write}(_wp_{name}, {value}, {starts}, {shape})")
         elif self.functional:
-            p.used_helpers.add("dus")
+            p.used_helpers.add(write)
             starts, shape = p.write_starts_shape(name)
-            self.em.line(f"{name} = _dus({name}, {value}, {starts}, {shape})")
+            self.em.line(f"{name} = _{write}({name}, {value}, {starts}, {shape})")
         else:
             tgt = p.write_target(name)
             self.em.line(f"{tgt} = {value}")
@@ -356,6 +380,61 @@ def emit_helpers(em: Emitter, used: set, lib: str) -> None:
         em.pop()
         em.line("val = jnp.broadcast_to(val, shape)")
         em.line("return lax.dynamic_update_slice(arr, val, starts)")
+        em.pop()
+    if "put" in used:
+        # Every start is a Python int except a sweep's traced level, whose
+        # box is one plane deep: the whole-extent test and the pads are
+        # decided while the kernel is traced.
+        em.line("def _put(arr, val, starts, shape):")
+        em.push()
+        em.line('"""arr with the box starts/shape set to val: plain assignment when the box')
+        em.line('is all of arr, otherwise an iota-mask select over arr."""')
+        em.line("val = jnp.asarray(val, dtype=arr.dtype)")
+        em.line("if val.ndim == len(shape) - 1:")
+        em.push()
+        em.line("val = val[..., None]")
+        em.pop()
+        em.line("if tuple(shape) == arr.shape:")
+        em.push()
+        em.line("return jnp.broadcast_to(val, shape)")
+        em.pop()
+        em.line("mask = None")
+        em.line("pads = []")
+        em.line("for ax, (s, d, n) in enumerate(zip(starts, shape, arr.shape)):")
+        em.push()
+        em.line("if d == n:")
+        em.push()
+        em.line("pads.append((0, 0))")
+        em.line("continue")
+        em.pop()
+        # a 1-D iota along ax: the mask broadcasts, no block-sized index array
+        em.line("io = lax.broadcasted_iota(jnp.int32, tuple(n if a == ax else 1 for a in range(arr.ndim)), ax)")
+        em.line("if isinstance(s, int) and d > 1:")
+        em.push()
+        em.line("pads.append((s, n - s - d))")
+        em.line("m = (io >= s) & (io < s + d)")
+        em.pop()
+        em.line("else:  # one row: val broadcasts along ax")
+        em.push()
+        em.line("pads.append(None)")
+        em.line("m = io == s")
+        em.pop()
+        em.line("mask = m if mask is None else mask & m")
+        em.pop()
+        em.line("val = jnp.broadcast_to(val, tuple(1 if p is None else d for p, d in zip(pads, shape)))")
+        em.line("if any(p not in (None, (0, 0)) for p in pads):")
+        em.push()
+        em.line("val = jnp.pad(val, [p or (0, 0) for p in pads])")
+        em.pop()
+        em.line("return jnp.where(mask, val, arr)")
+        em.pop()
+    if "kget" in used:
+        em.line("def _kget(x, k):")
+        em.push()
+        em.line('"""x[..., k] at a traced level k, as a masked max over the last axis (exact)."""')
+        em.line("low = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min")
+        em.line("kio = lax.broadcasted_iota(jnp.int32, (1,) * (x.ndim - 1) + (x.shape[-1],), x.ndim - 1)")
+        em.line("return jnp.max(jnp.where(kio == k, x, jnp.asarray(low, x.dtype)), axis=-1)")
         em.pop()
     if "cast" in used:
         em.line("def _cast(x, dt):")

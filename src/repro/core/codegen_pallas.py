@@ -28,8 +28,10 @@ TPU adaptation of the GridTools GPU schedule (see DESIGN.md §2):
   candidates; ``run`` accepts ``block=`` to override ``_BLOCK_DEFAULT``.
 
 Limitations (documented): written API fields may not be read at nonzero
-horizontal offsets (allocate a temporary instead); TPU hardware wants
-float32/bfloat16 — float64 kernels run under ``interpret=True`` only.
+horizontal offsets (allocate a temporary instead); Mosaic has no 64-bit
+vector types, so a float64 stencil runs only in the Pallas interpreter (off
+the TPU) and fails to build where the first device is a TPU.  Whether a
+kernel runs interpreted is ``StencilObject.interpreted``.
 """
 
 from __future__ import annotations
@@ -182,7 +184,7 @@ def generate_pallas_source(
     for plan in carry_plans.values():
         windowed.update(dict(plan.window))
 
-    printer = ArrayExprPrinter(impl, "jnp", axes_of, dtype_of)
+    printer = ArrayExprPrinter(impl, "jnp", axes_of, dtype_of, mosaic=True)
 
     # ---------------- kernel body ----------------
     kb = Emitter()
@@ -194,11 +196,13 @@ def generate_pallas_source(
     kb.line("gj = pl.program_id(1)")
     # issue every halo DMA up front, each on its own semaphore; waits are
     # deferred to each field's first-use multi-stage (software prefetch)
+    # into a scratch shaped by _window(): the tile, its halo, and tiling padding
     for i, n in enumerate(dma_inputs):
+        win = f"pl.ds(gi * _BI, _s_{n}.shape[0]), pl.ds(gj * _BJ, _s_{n}.shape[1])"
         if axes_of[n] == ("I", "J"):
-            src = f"{n}_hbm.at[pl.ds(gi * _BI, _BI + 2 * _H), pl.ds(gj * _BJ, _BJ + 2 * _H)]"
+            src = f"{n}_hbm.at[{win}]"
         else:
-            src = f"{n}_hbm.at[pl.ds(gi * _BI, _BI + 2 * _H), pl.ds(gj * _BJ, _BJ + 2 * _H), :]"
+            src = f"{n}_hbm.at[{win}, :]"
         kb.line(f"_cp_{n} = pltpu.make_async_copy({src}, _s_{n}, _dma_sems.at[{i}])")
         kb.line(f"_cp_{n}.start()")
     for s in impl.scalars:
@@ -251,10 +255,11 @@ def generate_pallas_source(
                 if axes_of[n] == ("I", "J"):
                     kb.line(f"{n} = _s_{n}[_H:_H + ni, _H:_H + nj]")
                 else:
-                    kb.line(f"{n} = _s_{n}[_H:_H + ni, _H:_H + nj, :]")
+                    kb.line(f"{n} = _s_{n}[_H:_H + ni, _H:_H + nj, :nk]")
                 kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (0, 0, 0)")
             else:
-                kb.line(f"{n} = _s_{n}[...]")
+                # read-only: every read loads its window from the scratch
+                printer.refs[n] = f"_s_{n}"
                 kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (_H, _H, 0)")
         if ms.order == ir.IterationOrder.PARALLEL:
             emit_parallel_block(impl, printer, kb, ms, mi, functional=True)
@@ -262,7 +267,7 @@ def generate_pallas_source(
             emit_sweep(impl, printer, kb, ms, mi, carry_plans[mi], "jnp")
 
     for n in written_api:
-        kb.line(f"{n}_out_ref[...] = {n}")
+        kb.line(f"{n}_out_ref[...] = {n}" if axes_of[n] == ("I", "J") else f"{n}_out_ref[:, :, :nk] = {n}")
 
     # ---------------- static schedule / VMEM metadata ----------------
     schedule = {
@@ -279,28 +284,28 @@ def generate_pallas_source(
         "window_planes": sum(windowed.values()),
     }
 
-    # per-tile VMEM estimate terms: (extra_i, extra_j, k_planes | -1 for nk, itemsize)
-    vmem_terms: List[Tuple[int, int, int, int]] = []
-    k_bytes = 0
-    for n in dma_inputs:
-        isz = np.dtype(dtype_of[n]).itemsize
-        vmem_terms.append((2 * H, 2 * H, -1 if axes_of[n] == ("I", "J", "K") else 1, isz))
+    # per-tile VMEM estimate terms (kind, extra_i, extra_j, copies, itemsize):
+    # "ijk" is a (bi+di, bj+dj, nk) block, "ij" a (bi+di, bj+dj) slab, "k" an
+    # (nk,) vector.  copies counts the VMEM buffers and in-kernel values; the
+    # sum bounds Mosaic's scoped allocation from above (docs/pallas.md).
+    vmem_terms: List[Tuple[str, int, int, int, int]] = []
+
+    def kind(name: str) -> str:
+        return {("I", "J", "K"): "ijk", ("I", "J"): "ij", ("K",): "k"}[axes_of[name]]
+
+    for n in dma_inputs:  # halo window + the slices loaded from it
+        vmem_terms.append((kind(n), 2 * H, 2 * H, 2, np.dtype(dtype_of[n]).itemsize))
     for n in k_inputs:
-        k_bytes += np.dtype(dtype_of[n]).itemsize
-    for n in written_api:
-        isz = np.dtype(dtype_of[n]).itemsize
-        vmem_terms.append((0, 0, -1 if axes_of[n] == ("I", "J", "K") else 1, isz))
+        vmem_terms.append(("k", 0, 0, 1, np.dtype(dtype_of[n]).itemsize))
+    for n in written_api:  # double-buffered output block + the in-kernel value
+        vmem_terms.append((kind(n), 0, 0, 3, np.dtype(dtype_of[n]).itemsize))
     for t in impl.temporaries:
         isz = np.dtype(t.dtype).itemsize
-        (ilo, ihi), (jlo, jhi), (klo, khi) = impl.extent_of(t.name).as_tuple()
+        (ilo, ihi), (jlo, jhi), _ = impl.extent_of(t.name).as_tuple()
         if t.name in windowed:
-            vmem_terms.append((ihi - ilo, jhi - jlo, windowed[t.name] + 1, isz))
-        elif axes_of[t.name] == ("I", "J", "K"):
-            vmem_terms.append((ihi - ilo, jhi - jlo, -1, isz))
-        elif axes_of[t.name] == ("I", "J"):
-            vmem_terms.append((ihi - ilo, jhi - jlo, 1, isz))
+            vmem_terms.append(("ij", ihi - ilo, jhi - jlo, windowed[t.name] + 1, isz))
         else:
-            k_bytes += isz
+            vmem_terms.append((kind(t.name), ihi - ilo, jhi - jlo, 1, isz))
 
     # ---------------- module assembly ----------------
     em = Emitter()
@@ -314,7 +319,6 @@ def generate_pallas_source(
     em.line("from jax.experimental.pallas import tpu as pltpu")
     emit_helpers(em, printer.used_helpers, "jnp")
     em.line()
-    em.line("INTERPRET = jax.devices()[0].platform != 'tpu'")
     em.line(f"_H = {H}")
     em.line(f"_BLOCK_DEFAULT = {tuple(block)!r}")
     em.line(f"_SCALARS = {[s.name for s in impl.scalars]!r}")
@@ -325,15 +329,69 @@ def generate_pallas_source(
     em.line(f"_DTYPES = {dict(sorted((n, dtype_of[n]) for n in api_names))!r}")
     em.line(f"SCHEDULE = {schedule!r}")
     em.line(f"_VMEM_TERMS = {vmem_terms!r}")
-    em.line(f"_VMEM_K_BYTES = {k_bytes!r}")
+    # A 32-bit kernel is traced with x64 off, so every index, loop level and
+    # Python constant in it is 32-bit as Mosaic requires.  A 64-bit kernel
+    # keeps x64 and runs only interpreted: Mosaic has no 64-bit vector types,
+    # so building one where the first device is a TPU fails here, at import.
+    wide = sorted({f.dtype for f in impl.all_fields if np.dtype(f.dtype).itemsize == 8})
+    em.line(f"_X64 = {bool(wide)!r}")
+    em.line("# off the TPU the kernel runs in the Pallas interpreter (StencilObject.interpreted)")
+    em.line("INTERPRET = jax.devices()[0].platform != 'tpu'")
+    if wide:
+        em.line("if not INTERPRET:")
+        em.push()
+        msg = (
+            f"pallas backend: stencil {impl.name!r} has {', '.join(wide)} fields, and kernels on a TPU "
+            "take 32-bit and narrower dtypes only (retype it to float32, or use the jax backend)"
+        )
+        em.line(f"raise TypeError({msg!r})")
+        em.pop()
+    em.line()
+    em.line("def _tiled(n, m):")
+    em.push()
+    em.line('"""n rounded up to a multiple of m."""')
+    em.line("return -(-n // m) * m")
+    em.pop()
+    em.line()
+    em.line("def _sublanes(dtype):")
+    em.push()
+    em.line('"""Rows of one (sublane, 128-lane) vector tile of dtype."""')
+    em.line("return 8 * max(1, 4 // np.dtype(dtype).itemsize)")
+    em.pop()
+    em.line()
+    em.line("def _window(n, bi, bj, nk):")
+    em.push()
+    em.line('"""Shape of input n\'s VMEM halo window for a (bi, bj) tile: the tile and its')
+    em.line('halo, the two minor axes rounded up to the vector tiling Mosaic slices by."""')
+    em.line("sub = _sublanes(_DTYPES[n])")
+    em.line("if _AXES[n] == ('I', 'J'):")
+    em.push()
+    em.line("return (_tiled(bi + 2 * _H, sub), _tiled(bj + 2 * _H, 128))")
+    em.pop()
+    em.line("return (bi + 2 * _H, _tiled(bj + 2 * _H, sub), _tiled(nk, 128))")
+    em.pop()
     em.line()
     em.line("def _vmem_bytes(bi, bj, nk):")
     em.push()
-    em.line('"""Per-tile VMEM footprint estimate for (bi, bj) at nk levels."""')
-    em.line("total = nk * _VMEM_K_BYTES")
-    em.line("for di, dj, kfac, isz in _VMEM_TERMS:")
+    em.line('"""Per-tile VMEM footprint estimate for (bi, bj) at nk levels, counted in')
+    em.line('whole (sublane, lane) vector tiles as Mosaic lays arrays out."""')
+    em.line("total = 0")
+    em.line("for kind, di, dj, copies, isz in _VMEM_TERMS:")
     em.push()
-    em.line("total += (bi + di) * (bj + dj) * (nk if kfac < 0 else kfac) * isz")
+    em.line("sub = 8 * max(1, 4 // isz)")
+    em.line("if kind == 'ijk':")
+    em.push()
+    em.line("size = (bi + di) * -(-(bj + dj) // sub) * sub * _tiled(nk, 128)")
+    em.pop()
+    em.line("elif kind == 'ij':")
+    em.push()
+    em.line("size = -(-(bi + di) // sub) * sub * _tiled(bj + dj, 128)")
+    em.pop()
+    em.line("else:")
+    em.push()
+    em.line("size = sub * _tiled(nk, 128)")
+    em.pop()
+    em.line("total += copies * size * isz")
     em.pop()
     em.line("return total")
     em.pop()
@@ -363,6 +421,7 @@ def generate_pallas_source(
     tail.line("bj = min(block[1], nj)")
     tail.line("nti = -(-ni // bi)")
     tail.line("ntj = -(-nj // bj)")
+    tail.line("nkp = _tiled(nk, 128)")
     tail.line("kernel = _make_kernel(bi, bj, nk)")
     tail.line("in_specs = []")
     tail.line("for s in _SCALARS:")
@@ -386,8 +445,8 @@ def generate_pallas_source(
     tail.push()
     tail.line("if _AXES[n] == ('I', 'J', 'K'):")
     tail.push()
-    tail.line("out_specs.append(pl.BlockSpec((bi, bj, nk), lambda i, j: (i, j, 0)))")
-    tail.line("out_shapes.append(jax.ShapeDtypeStruct((nti * bi, ntj * bj, nk), _DTYPES[n]))")
+    tail.line("out_specs.append(pl.BlockSpec((bi, bj, nkp), lambda i, j: (i, j, 0)))")
+    tail.line("out_shapes.append(jax.ShapeDtypeStruct((nti * bi, ntj * bj, nkp), _DTYPES[n]))")
     tail.pop()
     tail.line("elif _AXES[n] == ('I', 'J'):")
     tail.push()
@@ -399,28 +458,11 @@ def generate_pallas_source(
     tail.line("raise NotImplementedError('K-field outputs in pallas backend')")
     tail.pop()
     tail.pop()
-    tail.line("scratch = []")
-    tail.line("n_dma = 0")
-    tail.line("for n in _INPUT_API:")
-    tail.push()
-    tail.line("if n in _K_FIELDS:")
-    tail.push()
-    tail.line("continue")
-    tail.pop()
-    tail.line("n_dma += 1")
-    tail.line("if _AXES[n] == ('I', 'J'):")
-    tail.push()
-    tail.line("scratch.append(pltpu.VMEM((bi + 2 * _H, bj + 2 * _H), _DTYPES[n]))")
-    tail.pop()
-    tail.line("else:")
-    tail.push()
-    tail.line("scratch.append(pltpu.VMEM((bi + 2 * _H, bj + 2 * _H, nk), _DTYPES[n]))")
-    tail.pop()
-    tail.pop()
-    tail.line("if n_dma:")
+    tail.line("scratch = [pltpu.VMEM(_window(n, bi, bj, nk), _DTYPES[n]) for n in _INPUT_API if n not in _K_FIELDS]")
+    tail.line("if scratch:")
     tail.push()
     tail.line("# one DMA semaphore per prefetched input tile")
-    tail.line("scratch.append(pltpu.SemaphoreType.DMA((n_dma,)))")
+    tail.line("scratch.append(pltpu.SemaphoreType.DMA((len(scratch),)))")
     tail.pop()
     tail.line("call = pl.pallas_call(kernel, grid=(nti, ntj), in_specs=in_specs, out_specs=out_specs,")
     tail.line("                      out_shape=out_shapes, scratch_shapes=scratch, interpret=INTERPRET)")
@@ -436,8 +478,6 @@ def generate_pallas_source(
     tail.push()
     tail.line("args.append(jnp.asarray([scalars[s]], dtype=_DTYPES[_WRITTEN_API[0]]))")
     tail.pop()
-    tail.line("pad_i = nti * bi - ni")
-    tail.line("pad_j = ntj * bj - nj")
     tail.line("for n in _INPUT_API:")
     tail.push()
     tail.line("arr = fields[n]")
@@ -447,19 +487,24 @@ def generate_pallas_source(
     tail.line("args.append(jax.lax.dynamic_slice(arr, (ok,), (nk,)))")
     tail.line("continue")
     tail.pop()
+    tail.line("# edge-pad the region so the last tile's window stays in bounds")
+    tail.line("win = _window(n, bi, bj, nk)")
+    tail.line("pads = [(0, (nti - 1) * bi + win[0] - ni - 2 * _H), (0, (ntj - 1) * bj + win[1] - nj - 2 * _H)]")
     tail.line("if _AXES[n] == ('I', 'J'):")
     tail.push()
     tail.line("region = arr[oi - _H:oi + ni + _H, oj - _H:oj + nj + _H]")
-    tail.line("region = jnp.pad(region, ((0, pad_i), (0, pad_j)), mode='edge')")
     tail.pop()
     tail.line("else:")
     tail.push()
     tail.line("region = arr[oi - _H:oi + ni + _H, oj - _H:oj + nj + _H, ok:ok + nk]")
-    tail.line("region = jnp.pad(region, ((0, pad_i), (0, pad_j), (0, 0)), mode='edge')")
+    tail.line("pads.append((0, win[2] - nk))")
     tail.pop()
-    tail.line("args.append(region)")
+    tail.line("args.append(jnp.pad(region, pads, mode='edge'))")
     tail.pop()
+    tail.line("with jax.enable_x64(_X64):")
+    tail.push()
     tail.line("outs = call(*args)")
+    tail.pop()
     tail.line("updates = {}")
     tail.line("for n, new in zip(_WRITTEN_API, outs):")
     tail.push()
@@ -471,7 +516,7 @@ def generate_pallas_source(
     tail.pop()
     tail.line("else:")
     tail.push()
-    tail.line("updates[n] = arr.at[oi:oi + ni, oj:oj + nj, ok:ok + nk].set(new[:ni, :nj, :])")
+    tail.line("updates[n] = arr.at[oi:oi + ni, oj:oj + nj, ok:ok + nk].set(new[:ni, :nj, :nk])")
     tail.pop()
     tail.pop()
     tail.line("return updates")

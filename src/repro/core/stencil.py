@@ -112,6 +112,13 @@ class StencilObject:
         self._field_order = [f.name for f in impl.api_fields]
         self._jit_cache: Dict[Any, Callable] = {}
 
+    @property
+    def interpreted(self) -> bool:
+        """True when this stencil's Pallas kernel runs in the Pallas
+        interpreter rather than compiled by Mosaic (the backend interprets
+        off the TPU); False for every other backend."""
+        return self.backend == "pallas" and bool(getattr(self._module, "INTERPRET", False))
+
     # ------------------------------------------------------------------ binding
 
     def _bind(self, args, kwargs):
@@ -291,6 +298,7 @@ class StencilObject:
             )
             if exec_info is not None:
                 exec_info["schedule"] = getattr(self._module, "SCHEDULE", None)
+                exec_info["interpret"] = self.interpreted
                 if autotune_record is not None:
                     exec_info["autotune"] = autotune_record
         elif self._numpy_tiled:

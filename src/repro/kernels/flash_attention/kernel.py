@@ -22,7 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -168,7 +167,7 @@ def flash_attention_bhsd(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, dh), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

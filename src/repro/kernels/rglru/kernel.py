@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, y_ref, h_scratch, *, chunk: int):
     sc = pl.program_id(2)
@@ -70,7 +68,7 @@ def rglru_scan_bsd(
         out_specs=pl.BlockSpec((bb, chunk, bd), lambda i, j, s: (i, s, j)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), a.dtype),
         scratch_shapes=[pltpu.VMEM((bb, bd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -37,6 +37,7 @@ import signal
 from typing import Tuple
 
 import repro  # noqa: F401
+from repro.core.caching import configure_compile_cache
 from repro.obs import export as obs_export
 from repro.obs import slo as obs_slo
 from repro.obs import trace as otrace
@@ -256,6 +257,11 @@ def main() -> None:
     ap.add_argument("--no-slo", action="store_true", help="disable the default SLO objectives")
     args = ap.parse_args()
 
+    if args.supervise:
+        # the parent never touches a device: the chip belongs to the child
+        _supervise(args)
+        return
+    configure_compile_cache()
     if args.dry:
         engine = ServingEngine(window_ms=args.window_ms)
         entry = build_forecast_entry(engine, backend=args.backend, domain=tuple(args.domain), warm=False)
@@ -263,9 +269,6 @@ def main() -> None:
         return
     if args.load:
         asyncio.run(_load_test(args))
-        return
-    if args.supervise:
-        _supervise(args)
         return
     try:
         asyncio.run(_serve(args))

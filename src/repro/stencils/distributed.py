@@ -23,20 +23,14 @@ from typing import Dict, Optional, Tuple
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
+from repro.core.stencil import StencilObject
+from repro.parallel.halo import exchange_halo_2d
 
 
 def shard_map(body, *, mesh, in_specs, out_specs):
-    try:  # jax >= 0.5 spells the replication check 'check_vma'
-        return _shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-
-from repro.core.stencil import StencilObject  # noqa: E402  (after the shard_map compat shim)
-from repro.parallel.halo import exchange_halo_2d  # noqa: E402
+    """``jax.shard_map`` with the replication check off: stencil bodies mix
+    halo-exchanged and per-shard values the checker cannot type."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
 class DistributedStencil:

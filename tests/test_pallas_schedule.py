@@ -272,3 +272,38 @@ def test_schedule_surfaces_in_exec_info():
     assert sched["dma_inputs"] == ["rho", "w"]
     assert sched["window_fields"] == 2 and sched["window_planes"] == 2
     assert sched["full_carry_fields"] == 2
+
+
+def test_exec_info_says_the_kernel_ran_interpreted():
+    st = gtscript.stencil(backend="pallas", block=(4, 4))(vintg_defs)
+    assert st.interpreted  # no TPU here: the Pallas interpreter runs it
+    fs = {
+        n: storage.from_array(_rand((NI, NJ, NK), seed=s) + 2.0, backend="pallas")
+        for s, n in enumerate(("rho", "w", "out_dn", "out_up"))
+    }
+    info = {}
+    st(**fs, decay=np.float64(0.9), domain=(NI, NJ, NK), exec_info=info)
+    assert info["interpret"] is True
+    assert not gtscript.stencil(backend="jax")(vintg_defs).interpreted
+
+
+def test_float64_pallas_kernel_fails_to_build_on_a_tpu(monkeypatch):
+    """Mosaic has no 64-bit vector types: where the first device is a TPU, a
+    float64 Pallas stencil is refused when it is built, naming the dtype;
+    its float32 retyping builds and compiles instead of interpreting."""
+    import jax
+    import pytest
+
+    from repro.core import ir
+    from repro.core.stencil import build_from_definition
+
+    class _Tpu:
+        platform = "tpu"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Tpu()])
+    with pytest.raises(TypeError, match="float64"):
+        gtscript.stencil(backend="pallas", rebuild=True)(vintg_defs)
+    f32 = ir.retype_definition(frontend.parse_stencil_definition(vintg_defs, externals={}), {"float64": "float32"})
+    st = build_from_definition(f32, "pallas", rebuild=True)
+    assert not st.interpreted
+    st._module.INTERPRET = True  # the module stays cached for later tests

@@ -76,6 +76,28 @@ def _touch_and_sleep_cmd(marker: Path, sleep_s: float = 60.0):
     ]
 
 
+def test_supervise_parent_starts_no_jax_backend(tmp_path):
+    """``repro.launch.serve --supervise`` must leave the chip to its child: the
+    parent imports JAX but initializes no backend before it spawns."""
+    script = f"""
+import sys
+sys.path.insert(0, {str(Path(__file__).resolve().parent.parent / "src")!r})
+sys.argv = ["serve", "--supervise", "--port", "1", "--flight-dir", {str(tmp_path)!r}]
+import repro.launch.serve as serve
+from jax._src import xla_bridge
+
+def run_forever(self):
+    print("backends", xla_bridge.backends_are_initialized(), self.cmd[1:3])
+    raise SystemExit(0)
+
+serve.Supervisor.run_forever = run_forever
+serve.main()
+"""
+    res = subprocess.run([sys.executable, "-c", script], capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0, res.stderr[-2000:]
+    assert "backends False ['-m', 'repro.launch.serve']" in res.stdout
+
+
 def test_supervisor_spawns_and_probes_ready(tmp_path):
     marker = tmp_path / "ready"
     sup = Supervisor(
