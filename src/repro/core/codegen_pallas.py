@@ -26,6 +26,9 @@ TPU adaptation of the GridTools GPU schedule (see DESIGN.md §2):
   window depths) and ``_vmem_bytes`` (per-tile VMEM estimate) so the
   autotuner (``core/autotune.py``) can filter and time ``(BI, BJ)``
   candidates; ``run`` accepts ``block=`` to override ``_BLOCK_DEFAULT``.
+* The ``pallas_call`` is named ``KERNEL_PREFIX`` + the stencil's name, so
+  the compiled custom-call, and its events in a device profile, carry the
+  stencil's name.
 
 Limitations (documented): written API fields may not be read at nonzero
 horizontal offsets (allocate a temporary instead); Mosaic has no 64-bit
@@ -51,6 +54,9 @@ from .codegen_common import (
     multistage_plan,
 )
 from .gtscript import GTScriptSemanticError
+
+#: the name of every generated kernel starts with this, then the stencil's name
+KERNEL_PREFIX = "gt_"
 
 
 def _reads_of(impl: ir.StencilImplementation) -> Dict[str, List[Tuple[int, int, int]]]:
@@ -465,7 +471,8 @@ def generate_pallas_source(
     tail.line("scratch.append(pltpu.SemaphoreType.DMA((len(scratch),)))")
     tail.pop()
     tail.line("call = pl.pallas_call(kernel, grid=(nti, ntj), in_specs=in_specs, out_specs=out_specs,")
-    tail.line("                      out_shape=out_shapes, scratch_shapes=scratch, interpret=INTERPRET)")
+    tail.line("                      out_shape=out_shapes, scratch_shapes=scratch, interpret=INTERPRET,")
+    tail.line(f"                      name={KERNEL_PREFIX + impl.name!r})")
     tail.line("return jax.jit(call), (bi, bj, nti, ntj)")
     tail.pop()
     tail.line()

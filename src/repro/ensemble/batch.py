@@ -7,6 +7,12 @@ compiler slices per-member views for compilation and batches execution with
 ``jax.vmap`` — so everything the single-member toolchain knows (halos,
 origins, dtypes, (8, 128) alignment padding) is computed per member and is
 identical between batched and unbatched allocations.
+
+The host copies of the serving path are spans, one per phase, so a profile
+tells them apart: ``ensemble.stack`` (numpy stacking and broadcast copies),
+``ensemble.to_device`` (the put of a batched storage into its backend),
+``ensemble.wait`` (the host waiting for a gathered member's device result)
+and ``ensemble.to_host`` (device-to-host copies).
 """
 
 from __future__ import annotations
@@ -17,6 +23,7 @@ import numpy as np
 
 from repro.core import storage as core_storage
 from repro.core.storage import Storage
+from repro.obs import trace as otrace
 from repro.program.trace import ProgramError
 
 
@@ -104,38 +111,35 @@ def from_member_arrays(arrays, backend="numpy", default_origin=None, dtype=None,
     if isinstance(first, Storage):
         default_origin = default_origin if default_origin is not None else first.default_origin
         axes = axes if axes is not None else first.axes
-    data = np.stack(raws, axis=0)
-    if dtype is not None:
-        data = data.astype(dtype)
+    with otrace.span("ensemble.stack"):
+        data = np.stack(raws, axis=0)
+        if dtype is not None:
+            data = data.astype(dtype)
     if axes is None:
         axes = ("I", "J", "K")[: raws[0].ndim]
     if default_origin is None:
         default_origin = (0,) * raws[0].ndim
-    return Storage(
-        data, backend=backend, default_origin=(0,) + tuple(default_origin), axes=batched_axes(axes)
-    )
+    with otrace.span("ensemble.to_device"):
+        return Storage(
+            data, backend=backend, default_origin=(0,) + tuple(default_origin), axes=batched_axes(axes)
+        )
 
 
 def broadcast(value: Any, members: int, backend=None) -> Storage:
     """Replicate one field across ``members`` identical members (the batched
     form of an unperturbed initial condition)."""
+    with otrace.span("ensemble.to_host"):
+        arr = np.asarray(value.data if isinstance(value, Storage) else value)
+    with otrace.span("ensemble.stack"):
+        data = np.broadcast_to(arr, (int(members),) + arr.shape).copy()
     if isinstance(value, Storage):
         backend = backend or value.backend
-        data = np.broadcast_to(np.asarray(value.data), (int(members),) + tuple(value.shape)).copy()
-        return Storage(
-            data,
-            backend=backend,
-            default_origin=(0,) + tuple(value.default_origin),
-            axes=batched_axes(value.axes),
-        )
-    arr = np.asarray(value)
-    data = np.broadcast_to(arr, (int(members),) + arr.shape).copy()
-    return Storage(
-        data,
-        backend=backend or "numpy",
-        default_origin=(0,) * (arr.ndim + 1),
-        axes=batched_axes(("I", "J", "K")[: arr.ndim]),
-    )
+        origin, axes = (0,) + tuple(value.default_origin), batched_axes(value.axes)
+    else:
+        backend = backend or "numpy"
+        origin, axes = (0,) * (arr.ndim + 1), batched_axes(("I", "J", "K")[: arr.ndim])
+    with otrace.span("ensemble.to_device"):
+        return Storage(data, backend=backend, default_origin=origin, axes=axes)
 
 
 def scatter_members(arrays, members: int, *, template: Storage, backend=None) -> Storage:
@@ -174,12 +178,19 @@ def gather_member(batched, m: int) -> np.ndarray:
 
     The inverse of :func:`scatter_members` — used by the serving engine to
     peel request ``m``'s state out of a batched storage for streaming, so the
-    returned array must not alias device or batch memory."""
-    if isinstance(batched, Storage):
-        if not batched.is_member_batched:
-            raise EnsembleError(f"storage with axes {batched.axes} has no member axis to gather")
-        return np.array(np.asarray(batched.member(int(m)).data), copy=True)
-    return np.array(np.asarray(batched)[int(m)], copy=True)
+    returned array must not alias device or batch memory.  A device member is
+    waited for (``ensemble.wait``) before it is copied (``ensemble.to_host``)."""
+    if not isinstance(batched, Storage):
+        with otrace.span("ensemble.to_host"):
+            return np.array(np.asarray(batched)[int(m)], copy=True)
+    if not batched.is_member_batched:
+        raise EnsembleError(f"storage with axes {batched.axes} has no member axis to gather")
+    data = batched.member(int(m)).data
+    if hasattr(data, "block_until_ready"):
+        with otrace.span("ensemble.wait"):
+            data.block_until_ready()
+    with otrace.span("ensemble.to_host"):
+        return np.array(np.asarray(data), copy=True)
 
 
 def member_view(batched: Storage, m: int) -> Storage:

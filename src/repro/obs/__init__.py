@@ -8,8 +8,9 @@ scaling telemetry as first-class outputs; this package is that substrate:
 
 * :mod:`repro.obs.trace` — structured span tracer: nested spans on ONE
   monotonic clock, bounded ring-buffer retention, a strict no-op fast path
-  when disabled, and per-request trace-id correlation (one batched dispatch
-  span links every request that rode it).
+  when disabled, per-request trace-id correlation (one batched dispatch
+  span links every request that rode it), and the bridge that writes every
+  span into a collecting ``jax.profiler`` session under its name.
 * :mod:`repro.obs.sampling` — deterministic head-based trace sampling: the
   keep/drop decision is a pure hash of the request id, so the tracer can
   stay on in production at ``REPRO_TRACE_SAMPLE=0.1`` and a sampled-out
@@ -23,8 +24,7 @@ scaling telemetry as first-class outputs; this package is that substrate:
 * :mod:`repro.obs.flight` — the failure flight recorder: one self-contained
   JSON black box (spans + metrics + stats + config) dumped on worker death,
   crash-loop give-up, SLO breach, or SIGUSR2.
-* :mod:`repro.obs.export` — Chrome-trace/Perfetto JSON dump + validation,
-  and the optional ``jax.profiler`` annotation bridge.
+* :mod:`repro.obs.export` — Chrome-trace/Perfetto JSON dump + validation.
 
 Instrumented layers: stencil build (frontend → passes → codegen → autotune),
 program trace/compile, ensemble dispatch, and the full serving request
@@ -35,7 +35,7 @@ See docs/observability.md for the span taxonomy and metric names.
 """
 
 from . import export, flight, metrics, sampling, slo, trace
-from .export import chrome_trace, jax_profiler_span, validate_chrome_trace, write_chrome_trace
+from .export import chrome_trace, validate_chrome_trace, write_chrome_trace
 from .flight import FlightRecorder, load_bundle, validate_flight_bundle
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .sampling import SamplingPolicy, head_sampled
@@ -62,7 +62,6 @@ __all__ = [
     "export",
     "flight",
     "head_sampled",
-    "jax_profiler_span",
     "load_bundle",
     "metrics",
     "monotonic",

@@ -1,4 +1,4 @@
-"""Exporters: span buffer → Chrome-trace/Perfetto JSON, optional jax.profiler.
+"""Exporters: span buffer → Chrome-trace/Perfetto JSON.
 
 The span dicts produced by :mod:`repro.obs.trace` convert to the Chrome
 Trace Event format (the JSON flavor Perfetto, ``chrome://tracing`` and
@@ -16,10 +16,8 @@ Trace Event format (the JSON flavor Perfetto, ``chrome://tracing`` and
 extras leg assert against; ``python -m repro.obs.export TRACE.json``
 validates a captured file from the command line and prints a span census.
 
-:func:`jax_profiler_span` is the opt-in bridge to ``jax.profiler``: when jax
-is importable it opens a ``TraceAnnotation`` so serving dispatches show up
-inside an XLA device profile; otherwise (or on any profiler error) it is a
-no-op — telemetry must never take the dispatch down.
+Spans reach a ``jax.profiler`` device profile through the tracer itself
+(:mod:`repro.obs.trace`, the profiler bridge), not through an exporter.
 """
 
 from __future__ import annotations
@@ -27,8 +25,6 @@ from __future__ import annotations
 import json
 import os
 import sys
-import threading
-from contextlib import contextmanager
 from pathlib import Path
 from typing import Any, Dict, Iterable, List, Optional, Sequence
 
@@ -151,58 +147,6 @@ def request_events(data: Dict[str, Any], trace_id: str) -> List[Dict[str, Any]]:
         if trace_id in args.get("trace_ids", ()) or args.get("request_id") == trace_id:
             out.append(ev)
     return out
-
-
-# ---------------------------------------------------------------------------
-# jax.profiler bridge (optional)
-# ---------------------------------------------------------------------------
-
-_jax_profiler = None
-_jax_probe_lock = threading.Lock()
-_jax_probed = False
-
-
-def jax_profiler_available() -> bool:
-    global _jax_profiler, _jax_probed
-    if not _jax_probed:
-        with _jax_probe_lock:
-            if not _jax_probed:
-                try:
-                    from jax import profiler as _prof  # noqa: PLC0415
-
-                    _jax_profiler = _prof
-                except Exception:  # noqa: BLE001 — no jax, no profiler hook
-                    _jax_profiler = None
-                _jax_probed = True
-    return _jax_profiler is not None
-
-
-@contextmanager
-def jax_profiler_span(name: str):
-    """Annotate the enclosed work in a jax/XLA profile when jax is present;
-    transparently a no-op otherwise.
-
-    Only the *annotation* is guarded: an exception raised by the wrapped
-    block must propagate with its original type/message (retry-with-bisect
-    keys off it), so the body is never re-yielded from an ``except`` branch —
-    that would turn every dispatch failure into contextlib's
-    ``RuntimeError("generator didn't stop after throw()")``.
-    """
-    ctx = None
-    if jax_profiler_available():
-        try:
-            ctx = _jax_profiler.TraceAnnotation(name)
-            ctx.__enter__()
-        except Exception:  # noqa: BLE001 — profiling must never fail the dispatch
-            ctx = None
-    try:
-        yield
-    finally:
-        if ctx is not None:
-            try:
-                ctx.__exit__(None, None, None)
-            except Exception:  # noqa: BLE001, S110 — annotation teardown is best-effort
-                pass
 
 
 def _census(events: Iterable[Dict[str, Any]]) -> Dict[str, int]:

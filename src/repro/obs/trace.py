@@ -6,10 +6,10 @@ Chrome-trace/Perfetto JSON.  Design constraints, in order:
 
 1. **Disabled ≈ free.**  Serving and stencil hot paths call ``span()``
    unconditionally; when tracing is off the call returns one shared
-   :data:`NOOP_SPAN` singleton after a single attribute check — no
-   allocation, no clock read, no buffer write.  ``tests/test_obs.py``
-   asserts both the identity and a generous wall bound on a million
-   disabled calls.
+   :data:`NOOP_SPAN` singleton after an attribute check and the profiler
+   bridge's one ``is_enabled()`` call (below) — no allocation, no clock
+   read, no buffer write.  ``tests/test_obs.py`` asserts both the
+   identity and a generous wall bound on 100k disabled calls.
 2. **One clock.**  :func:`monotonic` is THE time source for every latency,
    deadline, and span timestamp in the serving stack (engine, client,
    watchdog) — mixing ``time.time`` with ``perf_counter`` arithmetic is how
@@ -26,6 +26,18 @@ many ``trace_ids`` (one batched dispatch serves several requests), and every
 span/event that touches a request lists its id — that is what lets one slow
 request be followed through admission, the shared batch dispatches it rode,
 and any retry/bisect events that hit it.
+
+**The profiler bridge.**  While a JAX profiler session is collecting
+(``jax.profiler.start_trace``), every :func:`span` also opens a profiler
+``TraceAnnotation`` of the same name on the current thread, whether this
+tracer is enabled or not.  The span then lands on the profiler's host plane,
+the clock the device trace shares, which is where the benchmark's per-layer
+readers look.  Only the name crosses, never the attributes: readers match
+exact names.  Outside a profiler session the bridge costs one
+``TraceAnnotation.is_enabled()`` check per span; a profiler error never
+reaches the caller, and an exception in the span's body propagates
+unchanged.  Retroactive spans (:meth:`Tracer.add_span`) and instant events
+are not bridged.
 
 Enable globally with ``REPRO_TRACE=1`` (capacity via
 ``REPRO_TRACE_CAPACITY``), programmatically with :func:`configure`, or
@@ -52,6 +64,29 @@ from typing import Any, Dict, Iterable, List, Optional
 
 from . import sampling as _sampling
 
+from jax.profiler import TraceAnnotation
+
+#: is a profiler session collecting?  One call per span (the bridge)
+_profiling = TraceAnnotation.is_enabled
+
+
+def _annotate(name: str):
+    """Open a profiler annotation called ``name``; None when the profiler
+    refuses: telemetry never fails the work it annotates."""
+    try:
+        annotation = TraceAnnotation(name)
+        annotation.__enter__()
+        return annotation
+    except Exception:  # noqa: BLE001 — profiling must never fail the caller
+        return None
+
+
+def _close(annotation) -> None:
+    try:
+        annotation.__exit__(None, None, None)
+    except Exception:  # noqa: BLE001, S110 — annotation teardown is best-effort
+        pass
+
 #: the ONE monotonic clock for spans, latencies, and deadlines (satellite:
 #: no mixed time.time/perf_counter arithmetic across engine/client/watchdog)
 monotonic = time.perf_counter
@@ -72,6 +107,7 @@ class Span:
         "events",
         "_tracer",
         "_token",
+        "_annotation",
     )
 
     def __init__(self, tracer: "Tracer", name: str, category: str, span_id: int,
@@ -87,6 +123,7 @@ class Span:
         self.events: List[Dict[str, Any]] = []
         self._tracer = tracer
         self._token: Optional[contextvars.Token] = None
+        self._annotation = None
 
     def set(self, key: str, value: Any) -> None:
         self.attrs[key] = value
@@ -102,9 +139,14 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = self._tracer._current.set(self)
+        if _profiling():
+            self._annotation = _annotate(self.name)
         return self
 
     def __exit__(self, exc_type, exc, _tb) -> bool:
+        if self._annotation is not None:
+            _close(self._annotation)
+            self._annotation = None
         if exc_type is not None:
             self.attrs["error"] = f"{exc_type.__name__}: {exc}"
         if self._token is not None:
@@ -153,6 +195,33 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+class _ProfiledSpan(_NoopSpan):
+    """A span the tracer does not record but a collecting profiler does:
+    the disabled (or sampled-out) path while a profile is taken."""
+
+    __slots__ = ("name", "_annotation")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._annotation = None
+
+    def __enter__(self) -> "_ProfiledSpan":
+        self._annotation = _annotate(self.name)
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        if self._annotation is not None:
+            _close(self._annotation)
+            self._annotation = None
+        return False
+
+
+def _unrecorded(name: str):
+    """What span() returns when the tracer keeps nothing: the no-op
+    singleton, or a profiler annotation while a profile is collecting."""
+    return _ProfiledSpan(name) if _profiling() else NOOP_SPAN
+
+
 class Tracer:
     """Span recorder: ring-buffered retention, contextvar nesting."""
 
@@ -192,14 +261,16 @@ class Tracer:
              **attrs: Any):
         """Open a span (use as a context manager).  Disabled → NOOP_SPAN.
         Sampling: a span whose trace ids ALL hash out (none forced) is
-        NOOP too — id-free spans (compiles, windows) are always kept."""
+        NOOP too — id-free spans (compiles, windows) are always kept.
+        While a profiler session collects, every span (recorded or not)
+        also annotates the profile under ``name`` (module docstring)."""
         if not self.enabled:
-            return NOOP_SPAN
+            return _unrecorded(name)
         ids = [str(t) for t in trace_ids]
         if trace_id is not None and str(trace_id) not in ids:
             ids.insert(0, str(trace_id))
         if ids and not self.sampling.sampled(ids):
-            return NOOP_SPAN
+            return _unrecorded(name)
         parent = self._current.get()
         return Span(
             self,
@@ -260,7 +331,8 @@ class Tracer:
         """Record a retroactive span from explicit timestamps (e.g. queue
         wait, measured between two points that no context manager brackets).
         ``force=True`` bypasses sampling and pins the ids, like
-        :meth:`event`."""
+        :meth:`event`.  Such a span never reaches a profile: the profiler
+        annotates only work it sees start and end."""
         if not self.enabled:
             return
         trace_ids = [str(t) for t in trace_ids]

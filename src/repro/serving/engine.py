@@ -78,7 +78,6 @@ import contextvars
 import itertools
 import json
 import math
-from contextlib import nullcontext
 from dataclasses import dataclass, field as dc_field
 from typing import Any, AsyncIterator, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -91,7 +90,6 @@ from repro.ensemble import batch as ens_batch
 from repro.obs import metrics as obs_metrics
 from repro.obs import slo as obs_slo
 from repro.obs import trace as otrace
-from repro.obs.export import jax_profiler_span
 from repro.obs.flight import FlightRecorder
 from repro.obs.trace import monotonic
 from repro.program.compile import ProgramObject
@@ -457,7 +455,10 @@ class ServingEngine:
         # a fixed tracer wins; otherwise spans follow the contextvar routing
         # (capture() overrides, REPRO_TRACE/configure() for the process default)
         self._tracer = tracer
-        self.jax_profile = bool(jax_profile)
+        # a no-op: every span reaches a collecting jax.profiler session
+        # through the tracer's bridge; still accepted because the
+        # benchmark's serving harness (bench/) passes it
+        del jax_profile
         # every operational counter lives in the registry; stats() is a view
         # of it, and the transport serves to_prometheus() on GET /metrics
         self.metrics = metrics if metrics is not None else obs_metrics.MetricsRegistry()
@@ -1100,11 +1101,6 @@ class ServingEngine:
                 return
             try:
                 t1 = monotonic()
-                profiled = (
-                    jax_profiler_span(f"serving.dispatch[{entry.name}]")
-                    if self.jax_profile
-                    else nullcontext()
-                )
                 with self._span(
                     "serving.dispatch",
                     trace_ids=[r.request_id for r, _ in live],
@@ -1113,7 +1109,7 @@ class ServingEngine:
                     steps=seg,
                     members=m,
                     requests=len(live),
-                ), profiled:
+                ):
                     # run_in_executor does not propagate contextvars, so pin
                     # the resolved tracer (and the open dispatch span) into a
                     # context snapshot the executor thread runs under — the

@@ -112,11 +112,13 @@ def test_disabled_span_is_the_noop_singleton():
     assert len(tr) == 0
 
 
-def test_disabled_path_overhead_is_bounded():
-    """100k disabled span() round-trips must stay well under a second — the
+def test_disabled_path_overhead_is_bounded(monkeypatch):
+    """100k disabled span() round-trips, the profiler bridge's
+    ``is_enabled()`` check included, must stay well under a second — the
     serving hot path calls this unconditionally per dispatch/gather."""
     tr = otrace.Tracer(enabled=False)
     n = 100_000
+    assert not otrace._profiling()  # the real check, on the timed path
     t0 = time.perf_counter()
     for _ in range(n):
         with tr.span("hot", category="serving"):
@@ -124,6 +126,13 @@ def test_disabled_path_overhead_is_bounded():
     dt = time.perf_counter() - t0
     assert len(tr) == 0
     assert dt < 1.0, f"{n} disabled spans took {dt:.3f}s"
+    # the check is made once per span() and is all the bridge adds
+    calls = []
+    monkeypatch.setattr(otrace, "_profiling", lambda: calls.append(1) or False)
+    for _ in range(1000):
+        with tr.span("hot", category="serving"):
+            pass
+    assert len(calls) == 1000
 
 
 # ---------------------------------------------------------------------------
@@ -472,43 +481,103 @@ def test_export_cli_exit_codes(tmp_path, capsys):
     assert obs_export.main(["--bogus-flag", str(good)]) == 2
 
 
-def test_jax_profiler_span_never_raises():
-    with obs_export.jax_profiler_span("unit-test"):
+class _Annotations:
+    """A stand-in for the profiler's annotation that records what opened
+    and closed."""
+
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("open", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name))
+
+
+@pytest.fixture()
+def collecting(monkeypatch):
+    """A profiler session that is collecting, as the bridge sees it."""
+    _Annotations.log = []
+    monkeypatch.setattr(otrace, "_profiling", lambda: True)
+    monkeypatch.setattr(otrace, "TraceAnnotation", _Annotations)
+    return _Annotations.log
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_jax_profiler_span_never_raises(collecting, enabled):
+    """While a profile collects, every span annotates it under its name
+    alone, whether the tracer records the span or not."""
+    tr = otrace.Tracer(enabled=enabled)
+    with tr.span("unit-test", trace_id="r1", heavy=1) as sp:
+        sp.set("k", 1)
         x = 1 + 1
     assert x == 2
+    assert collecting == [("open", "unit-test"), ("close", "unit-test")]
+    assert len(tr) == int(enabled)
 
 
-def test_jax_profiler_span_propagates_body_exception():
+@pytest.mark.parametrize("enabled", [False, True])
+def test_jax_profiler_span_propagates_body_exception(collecting, enabled):
     """The wrapped block's exception must surface with its original
-    type/message — retry-with-bisect keys off it, so masking it behind
-    contextlib's 'generator didn't stop after throw()' feeds the safety
-    path a bogus error."""
+    type/message — retry-with-bisect keys off it — and the annotation is
+    closed on the way out."""
 
     class _Boom(RuntimeError):
         pass
 
+    tr = otrace.Tracer(enabled=enabled)
     with pytest.raises(_Boom, match="original dispatch failure"):
-        with obs_export.jax_profiler_span("unit-test"):
+        with tr.span("unit-test"):
             raise _Boom("original dispatch failure")
+    assert collecting == [("open", "unit-test"), ("close", "unit-test")]
 
 
-def test_jax_profiler_span_survives_broken_annotation(monkeypatch):
-    """A profiler whose TraceAnnotation blows up on entry must neither fail
-    the dispatch nor swallow the body's own exception."""
+@pytest.mark.parametrize("enabled", [False, True])
+@pytest.mark.parametrize("broken", ["__init__", "__enter__", "__exit__"])
+def test_jax_profiler_span_survives_broken_annotation(monkeypatch, enabled, broken):
+    """A profiler annotation that blows up must neither fail the work it
+    annotates nor swallow the body's own exception."""
 
-    class _BrokenProfiler:
-        class TraceAnnotation:
-            def __init__(self, name):
-                raise OSError("profiler backend unavailable")
+    class _Broken(_Annotations):
+        pass
 
-    monkeypatch.setattr(obs_export, "_jax_profiler", _BrokenProfiler)
-    monkeypatch.setattr(obs_export, "_jax_probed", True)
-    with obs_export.jax_profiler_span("unit-test"):
+    def fail(*_a, **_k):
+        raise OSError("profiler backend unavailable")
+
+    setattr(_Broken, broken, fail)
+    monkeypatch.setattr(otrace, "_profiling", lambda: True)
+    monkeypatch.setattr(otrace, "TraceAnnotation", _Broken)
+    tr = otrace.Tracer(enabled=enabled)
+    with tr.span("unit-test"):
         x = 1 + 1
     assert x == 2
     with pytest.raises(ValueError, match="body failure"):
-        with obs_export.jax_profiler_span("unit-test"):
+        with tr.span("unit-test"):
             raise ValueError("body failure")
+    assert len(tr) == 2 * int(enabled)
+
+
+def test_no_profile_no_annotation(monkeypatch):
+    """Outside a profiler session the bridge opens nothing: a disabled
+    tracer hands back the no-op singleton, an enabled one only records."""
+    opened = []
+    monkeypatch.setattr(otrace, "TraceAnnotation", lambda name: opened.append(name))
+    assert not otrace._profiling()
+    assert otrace.Tracer(enabled=False).span("quiet") is otrace.NOOP_SPAN
+    tr = otrace.Tracer(enabled=True)
+    with tr.span("quiet"):
+        pass
+    assert len(tr) == 1 and opened == []
+
+
+def test_retroactive_spans_and_events_are_not_bridged(collecting):
+    tr = otrace.Tracer(enabled=True)
+    tr.add_span("serving.queue", 0.0, 1.0)
+    tr.event("marker")
+    assert collecting == [] and len(tr) == 2
 
 
 # ---------------------------------------------------------------------------
@@ -530,11 +599,57 @@ def test_stencil_exec_info_trace_opt_in():
     info = {"trace": True}
     st(a, b, domain=(4, 4, 3), exec_info=info)
     events = obs_export.validate_chrome_trace(info["trace"])
-    assert any(e["name"] == "stencil.run" for e in events)
+    assert any(e["name"] == "stencil.call" for e in events)
     # the opt-in never leaks into the process tracer or later calls
     info2 = {}
     st(a, b, domain=(4, 4, 3), exec_info=info2)
     assert "trace" not in info2
+
+
+def test_stencil_call_span_covers_binding_and_validation():
+    """``stencil.call`` spans the whole call: a call refused while its
+    arguments are validated is still inside it, and records the error."""
+    from repro.core import gtscript, storage
+    from repro.core.gtscript import PARALLEL, Field, computation, interval
+
+    def defs(a: Field[np.float64], b: Field[np.float64]):
+        with computation(PARALLEL), interval(...):
+            b = a + 1.0  # noqa: F841
+
+    st = gtscript.stencil(backend="numpy")(defs)
+    a = storage.from_array(np.zeros((4, 4, 3)), backend="numpy")
+    b = storage.from_array(np.zeros((4, 4, 3)), backend="numpy")
+    with otrace.capture() as tr:
+        st(a, b, domain=(4, 4, 3))
+        with pytest.raises(ValueError):
+            st(a, b, domain=(9, 4, 3))
+    calls = [sp for sp in tr.snapshot() if sp["name"] == "stencil.call"]
+    assert [sp["attrs"]["domain"] for sp in calls] == [[4, 4, 3], [9, 4, 3]]
+    assert "error" not in calls[0]["attrs"] and "ValueError" in calls[1]["attrs"]["error"]
+    assert all(sp["name"] != "stencil.run" for sp in tr.snapshot())
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_ensemble_host_copies_are_spans_by_phase(backend):
+    """Scatter stacks then puts; broadcast copies to the host, stacks, puts;
+    gather waits for a device member, then copies it to the host."""
+    from repro.core import storage
+    from repro.ensemble import batch as ens_batch
+
+    tmpl = storage.from_array(np.ones((4, 4, 3)), backend=backend)
+
+    def phases(fn):
+        with otrace.capture() as tr:
+            out = fn()
+        return out, [sp["name"] for sp in tr.snapshot()]
+
+    batched, names = phases(lambda: ens_batch.scatter_members([np.ones((4, 4, 3))] * 2, 4, template=tmpl))
+    assert names == ["ensemble.stack", "ensemble.to_device"]
+    _, names = phases(lambda: ens_batch.broadcast(tmpl, 4))
+    assert names == ["ensemble.to_host", "ensemble.stack", "ensemble.to_device"]
+    got, names = phases(lambda: ens_batch.gather_member(batched, 3))
+    assert names == (["ensemble.wait"] if backend == "jax" else []) + ["ensemble.to_host"]
+    np.testing.assert_array_equal(got, np.ones((4, 4, 3)))
 
 
 # ---------------------------------------------------------------------------

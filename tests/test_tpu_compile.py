@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -97,8 +98,11 @@ def _build(name):
 @pytest.mark.parametrize("name", ["hdiff", "vadv"])
 def test_pallas_kernel_compiles_for_v5e(one_chip, name, nk):
     """The default tile and every tile the autotuner's VMEM filter keeps
-    compile: a kept tile that Mosaic refuses is a bug in ``_vmem_bytes``."""
+    compile: a kept tile that Mosaic refuses is a bug in ``_vmem_bytes``.
+    The compiled custom-call is named for its stencil, so a device profile
+    tells the kernels apart."""
     from repro.core import autotune
+    from repro.core.codegen_pallas import KERNEL_PREFIX
 
     st = _build(name)
     assert "dynamic_update_slice" not in st.generated_source
@@ -110,7 +114,10 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, name, nk):
     for block in kept:
         assert st._module._vmem_bytes(*block, nk) <= autotune.VMEM_BUDGET_BYTES
         compiled = _compile_stencil(st, one_chip, domain, block)
-        assert "tpu_custom_call" in compiled.as_text()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        kernel = re.escape(KERNEL_PREFIX + st.name)
+        assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(", text), f"no custom-call named {kernel}"
 
 
 def _forecast_program(domain):
