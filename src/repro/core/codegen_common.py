@@ -76,15 +76,18 @@ class ArrayExprPrinter:
         lib: str,  # 'np' | 'jnp'
         axes_of: Dict[str, Tuple[str, ...]],
         dtype_of: Dict[str, str],
-        mosaic: bool = False,
+        layout: Optional[str] = None,
     ):
         self.impl = impl
         self.lib = lib
-        # Pallas TPU kernels (codegen_pallas): Mosaic lowers no dynamic_slice
-        # or dynamic_update_slice, so writes go through ``_put`` (plain
-        # assignment or an iota-mask select) and plane reads at the traced
-        # sweep level through ``_kget`` (a masked lane reduction).
-        self.mosaic = mosaic
+        # Pallas TPU kernels (codegen_pallas) set ``layout``; Mosaic lowers no
+        # dynamic_slice or dynamic_update_slice.  "k_minor" (PARALLEL-only
+        # kernels): arrays are values with K last, written through ``_put``
+        # (plain assignment or an iota-mask select).  "k_major" (kernels with
+        # a FORWARD/BACKWARD multi-stage): (I, J, K) arrays are VMEM refs laid
+        # out (K, I, J), so level k is the plane ``ref[k, ...]``, read and
+        # stored by indexing the leading axis.
+        self.layout = layout
         self.axes_of = axes_of
         self.dtype_of = dtype_of
         self.mode = "block"
@@ -107,8 +110,9 @@ class ArrayExprPrinter:
         # trailing reads hit the rolling history ``_wh_<name>_<q>`` instead of
         # a full 3-D array.  Bound by emit_sweep for the active multi-stage.
         self.window: Dict[str, int] = {}
-        # Pallas inputs read straight from their VMEM halo scratch ref
-        # (name -> ref expression) rather than from an in-kernel value copy
+        # Pallas fields read straight from a ref (name -> ref expression):
+        # an input's VMEM halo scratch rather than an in-kernel value copy,
+        # and in a K-major kernel the K columns and (I, J) outputs
         self.refs: Dict[str, str] = {}
 
     # -- region slices ---------------------------------------------------------
@@ -138,10 +142,27 @@ class ArrayExprPrinter:
             return f"_ok_{name} + {self.k0}{_c(dk)}:_ok_{name} + {self.k1}{_c(dk)}"
         return f"_ok_{name} + k{_c(dk)}"
 
-    def _kplane(self, array: str, name: str, dk: int) -> str:
-        """Mosaic form of ``array`` (K last) read at the traced sweep level."""
-        self.used_helpers.add("kget")
-        return f"_kget({array}, {self._kslice(name, dk)})"
+    def _kmajor_index(self, name: str, di: int, dj: int, dk: int) -> str:
+        """``name``'s region in a K-major kernel: the K index leads, then I, J.
+        A K field is an (nk, 1, 1) column, so it broadcasts over the plane."""
+        axes = self.axes_of[name]
+        parts = [self._kslice(name, dk)] if "K" in axes else []
+        if "I" in axes:
+            parts.extend(self._hslices(name, di, dj))
+        return f"{self.refs.get(name, name)}[{', '.join(parts)}]"
+
+    def kmajor_store(self, name: str) -> Tuple[str, str]:
+        """(target, shape) of a stage's write to ``name`` in a K-major kernel."""
+        (ilo, ihi), (jlo, jhi), _ = self.extent.as_tuple()
+        axes = self.axes_of[name]
+        shape = []
+        if "K" in axes and self.mode == "block":
+            shape.append(f"{self.k1} - {self.k0}")
+        if "I" in axes:
+            shape.extend([f"ni{_c(ihi - ilo)}", f"nj{_c(jhi - jlo)}"])
+        else:
+            shape.extend(["1", "1"])
+        return self._kmajor_index(name, 0, 0, 0), f"({', '.join(shape)})"
 
     def read(self, fa: ir.FieldAccess) -> str:
         name = fa.name
@@ -154,15 +175,15 @@ class ArrayExprPrinter:
                 return f"_wp_{name}[{si}, {sj}]"
             return f"_wh_{name}_{abs(dk)}[{si}, {sj}]"
         axes = self.axes_of[name]
+        if self.layout == "k_major":
+            region = self._kmajor_index(name, di, dj, dk)
+            return f"{region}[None]" if axes == ("I", "J") and self.mode == "block" else region
         # a ref (Pallas VMEM scratch) is loaded window by window: no None
-        # axes in its index, and its K extent is padded past nk
+        # axes in its index
         ref = self.refs.get(name)
         arr = ref or name
-        kall = ":nk" if ref else ":"
         if axes == ("I", "J", "K"):
             si, sj = self._hslices(name, di, dj)
-            if self.mode == "plane" and self.mosaic:
-                return self._kplane(f"{arr}[{si}, {sj}, {kall}]", name, dk)
             return f"{arr}[{si}, {sj}, {self._kslice(name, dk)}]"
         if axes == ("I", "J"):
             si, sj = self._hslices(name, di, dj)
@@ -172,8 +193,6 @@ class ArrayExprPrinter:
         if axes == ("K",):
             if self.mode == "block":
                 return f"{arr}[None, None, {self._kslice(name, dk)}]"
-            if self.mosaic:
-                return self._kplane(arr, name, dk)
             return f"{arr}[{self._kslice(name, dk)}]"
         raise NotImplementedError(f"axes {axes}")
 
@@ -298,15 +317,24 @@ class ArrayStmtEmitter:
         if mask is not None:
             old = p.read(ir.FieldAccess(name, (0, 0, 0)))
             value = f"{p.lib}.where({mask}, {value}, {old})"
-        write = "put" if p.mosaic else "dus"
+        write = "put" if p.layout else "dus"
         if name in p.locals_:
             # demoted temporary: direct variable binding, no field write
             self.em.line(f"{name} = {value}")
         elif p.mode == "plane" and name in p.window:
             # k-blocked sweep temporary: write the current 2-D plane
-            p.used_helpers.add(write)
             starts, shape = p.plane_write_starts_shape(name)
-            self.em.line(f"_wp_{name} = _{write}(_wp_{name}, {value}, {starts}, {shape})")
+            if p.layout == "k_major" and p.extent.as_tuple()[:2] == p.impl.extent_of(name).as_tuple()[:2]:
+                # the stage covers the whole plane: a plain rebinding
+                p.used_helpers.add("fit")
+                self.em.line(f"_wp_{name} = _fit({value}, {shape}, '{p.dtype_of[name]}')")
+            else:
+                p.used_helpers.add(write)
+                self.em.line(f"_wp_{name} = _{write}(_wp_{name}, {value}, {starts}, {shape})")
+        elif p.layout == "k_major":
+            p.used_helpers.add("fit")
+            target, shape = p.kmajor_store(name)
+            self.em.line(f"{target} = _fit({value}, {shape}, '{p.dtype_of[name]}')")
         elif self.functional:
             p.used_helpers.add(write)
             starts, shape = p.write_starts_shape(name)
@@ -382,8 +410,8 @@ def emit_helpers(em: Emitter, used: set, lib: str) -> None:
         em.line("return lax.dynamic_update_slice(arr, val, starts)")
         em.pop()
     if "put" in used:
-        # Every start is a Python int except a sweep's traced level, whose
-        # box is one plane deep: the whole-extent test and the pads are
+        # Every start is a Python int (a K-major kernel stores its sweep
+        # levels through refs): the whole-extent test and the pads are
         # decided while the kernel is traced.
         em.line("def _put(arr, val, starts, shape):")
         em.push()
@@ -428,13 +456,11 @@ def emit_helpers(em: Emitter, used: set, lib: str) -> None:
         em.pop()
         em.line("return jnp.where(mask, val, arr)")
         em.pop()
-    if "kget" in used:
-        em.line("def _kget(x, k):")
+    if "fit" in used:
+        em.line("def _fit(val, shape, dtype):")
         em.push()
-        em.line('"""x[..., k] at a traced level k, as a masked max over the last axis (exact)."""')
-        em.line("low = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min")
-        em.line("kio = lax.broadcasted_iota(jnp.int32, (1,) * (x.ndim - 1) + (x.shape[-1],), x.ndim - 1)")
-        em.line("return jnp.max(jnp.where(kio == k, x, jnp.asarray(low, x.dtype)), axis=-1)")
+        em.line('"""val as dtype, broadcast to shape: the value a ref store takes."""')
+        em.line("return jnp.broadcast_to(jnp.asarray(val, dtype=dtype), shape)")
         em.pop()
     if "cast" in used:
         em.line("def _cast(x, dt):")
@@ -493,11 +519,14 @@ def emit_sweep(
     mi: int,
     plan,  # analysis.SweepCarryPlan
     lib: str,
+    carry_full: bool = True,
 ) -> None:
     """Emit a FORWARD/BACKWARD multi-stage as ``lax.fori_loop``s carrying only
     the liveness-proven state (shared by the jax and pallas backends).
 
-    Full fields are carried as whole arrays, exactly as before.  Window
+    Full fields are carried as whole arrays, unless ``carry_full`` is off:
+    a K-major Pallas kernel holds them in VMEM refs the loop body indexes
+    and stores into, so only the window planes ride the carry.  Window
     fields carry ``depth`` rolling 2-D history planes (``_wh_<name>_<q>`` is
     the plane ``q`` iterations behind the sweep) plus a per-iteration current
     plane ``_wp_<name>`` — the k-blocking that keeps a sweep's VMEM live set
@@ -520,7 +549,7 @@ def emit_sweep(
         for q in range(1, depth + 1):
             em.line(f"_wh_{name}_{q} = {lib}.zeros({plane_shape(name)}, dtype='{dt}')")
     printer.window = dict(plan.window)
-    carried = list(plan.full) + [
+    carried = (list(plan.full) if carry_full else []) + [
         f"_wh_{n}_{q}" for n, d in plan.window for q in range(1, d + 1)
     ]
     carry = ", ".join(carried)

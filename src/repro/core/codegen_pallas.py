@@ -15,15 +15,20 @@ TPU adaptation of the GridTools GPU schedule (see DESIGN.md §2):
   the tile is VMEM-resident: intermediate stages (temporaries) never touch
   HBM.  This is the GridTools fusion argument restated for the TPU memory
   hierarchy — the memory-roofline win of the backend.
-* PARALLEL multi-stages vectorize over the whole (tile_i, tile_j, k) block;
-  FORWARD/BACKWARD multi-stages run **k-blocked** ``lax.fori_loop``s that
-  carry only the liveness-proven state (``analysis.sequential_carry_plan``):
-  API outputs and cross-multi-stage temporaries stay full 3-D, sweep-local
-  recurrence temporaries collapse to a rolling window of 2-D planes — which
-  is what frees VMEM headroom for larger tiles.
+* The in-kernel layout is chosen from the IR.  A PARALLEL-only kernel keeps
+  every array ``(tile_i, tile_j, k)``, K on lanes, and vectorizes each
+  multi-stage over the whole block.  A kernel with a FORWARD/BACKWARD
+  multi-stage goes **K-major**: each (I, J, K) array is a VMEM ref laid out
+  ``(k, tile_i, tile_j)`` (J on lanes, I on sublanes), filled from its halo
+  window by one transpose per I row, so a sweep level is the plane
+  ``ref[k]`` and a vertical offset a leading-axis slice.  Its sweeps run
+  **k-blocked** ``lax.fori_loop``s (``analysis.sequential_carry_plan``):
+  API outputs and cross-multi-stage temporaries stay full 3-D refs,
+  sweep-local recurrence temporaries collapse to a rolling window of 2-D
+  planes — which is what frees VMEM headroom for larger tiles.
 * Outputs are written back through regular non-overlapping BlockSpecs.
-* The generated module exports ``SCHEDULE`` (DMA waits, carried planes,
-  window depths) and ``_vmem_bytes`` (per-tile VMEM estimate) so the
+* The generated module exports ``SCHEDULE`` (layout, DMA waits, carried
+  planes, window depths) and ``_vmem_bytes`` (per-tile VMEM estimate) so the
   autotuner (``core/autotune.py``) can filter and time ``(BI, BJ)``
   candidates; ``run`` accepts ``block=`` to override ``_BLOCK_DEFAULT``.
 * The ``pallas_call`` is named ``KERNEL_PREFIX`` + the stencil's name, so
@@ -190,7 +195,24 @@ def generate_pallas_source(
     for plan in carry_plans.values():
         windowed.update(dict(plan.window))
 
-    printer = ArrayExprPrinter(impl, "jnp", axes_of, dtype_of, mosaic=True)
+    # K-major kernels hold each (I, J, K) array as a (k, i, j) VMEM ref: the
+    # DMA'd inputs' transposed windows, pure outputs and temporaries, in the
+    # order they follow the DMA semaphores among the kernel's arguments
+    kmajor = any(ms.order != ir.IterationOrder.PARALLEL for ms in impl.multi_stages)
+    layout = "k_major" if kmajor else "k_minor"
+    arrays: List[Tuple[str, str]] = []  # (name, shape expression in bi, bj, nk)
+    if kmajor:
+        arrays += [(n, f"(nk,) + _window({n!r}, bi, bj, nk)[:2]") for n in dma_inputs if axes_of[n] == ("I", "J", "K")]
+        arrays += [(n, "(nk, bi, bj)") for n in written_api if n not in inout_api and axes_of[n] == ("I", "J", "K")]
+        for t in impl.temporaries:
+            if t.name in windowed:
+                continue
+            (ilo, ihi), (jlo, jhi), (klo, khi) = impl.extent_of(t.name).as_tuple()
+            dims = {"K": f"nk{_c(khi - klo)}", "I": f"bi{_c(ihi - ilo)}", "J": f"bj{_c(jhi - jlo)}"}
+            shape = [dims[a] for a in ("K", "I", "J") if a in t.axes] + (["1", "1"] if t.axes == ("K",) else [])
+            arrays.append((t.name, f"({', '.join(shape)})"))
+
+    printer = ArrayExprPrinter(impl, "jnp", axes_of, dtype_of, layout=layout)
 
     # ---------------- kernel body ----------------
     kb = Emitter()
@@ -215,13 +237,22 @@ def generate_pallas_source(
         kb.line(f"{s.name} = {s.name}_smem[0]")
     # K fields arrive whole in VMEM — no DMA to wait on
     for n in k_inputs:
-        kb.line(f"{n} = {n}_vmem[...]")
+        if kmajor:
+            printer.refs[n] = f"{n}_vmem"
+        else:
+            kb.line(f"{n} = {n}_vmem[...]")
         kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (0, 0, 0)")
     # pure outputs start as zeros (functional in-kernel arrays)
     for n in written_api:
         if n in inout_api:
             continue  # bound from the DMA'd scratch at first use
         axes = axes_of[n]
+        if kmajor:
+            # a pure output is written on every plane of the tile: no zeros
+            if axes == ("I", "J"):
+                printer.refs[n] = f"{n}_out_ref"
+            kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (0, 0, 0)")
+            continue
         if axes == ("I", "J", "K"):
             shape = "(ni, nj, nk)"
         elif axes == ("I", "J"):
@@ -247,7 +278,10 @@ def generate_pallas_source(
         else:
             shape = f"(nk{_c(khi - klo)},)"
             origin = (0, 0, -klo)
-        kb.line(f"{t.name} = jnp.zeros({shape}, dtype='{t.dtype}')")
+        if kmajor:
+            kb.line(f"{t.name}[...] = jnp.zeros({t.name}.shape, dtype={t.name}.dtype)")
+        else:
+            kb.line(f"{t.name} = jnp.zeros({shape}, dtype='{t.dtype}')")
         kb.line(f"_oi_{t.name}, _oj_{t.name}, _ok_{t.name} = {origin}")
 
     # ----- fused multi-stages, with DMA waits at each input's first use
@@ -257,7 +291,18 @@ def generate_pallas_source(
             if first_use[n] != mi:
                 continue
             kb.line(f"_cp_{n}.wait()")
-            if n in inout_api:
+            if kmajor:
+                if axes_of[n] == ("I", "J", "K"):
+                    # one (J, K) -> (K, J) transpose per I row of the window
+                    kb.line(f"for _i in range(_s_{n}.shape[0]):")
+                    kb.push()
+                    kb.line(f"{n}[:, _i, :] = _s_{n}[_i].T[:nk]")
+                    kb.pop()
+                else:
+                    # an (I, J) window is already laid out as a plane
+                    printer.refs[n] = f"_s_{n}"
+                kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (_H, _H, 0)")
+            elif n in inout_api:
                 if axes_of[n] == ("I", "J"):
                     kb.line(f"{n} = _s_{n}[_H:_H + ni, _H:_H + nj]")
                 else:
@@ -270,13 +315,23 @@ def generate_pallas_source(
         if ms.order == ir.IterationOrder.PARALLEL:
             emit_parallel_block(impl, printer, kb, ms, mi, functional=True)
         else:
-            emit_sweep(impl, printer, kb, ms, mi, carry_plans[mi], "jnp")
+            emit_sweep(impl, printer, kb, ms, mi, carry_plans[mi], "jnp", carry_full=False)
 
     for n in written_api:
-        kb.line(f"{n}_out_ref[...] = {n}" if axes_of[n] == ("I", "J") else f"{n}_out_ref[:, :, :nk] = {n}")
+        if not kmajor:
+            kb.line(f"{n}_out_ref[...] = {n}" if axes_of[n] == ("I", "J") else f"{n}_out_ref[:, :, :nk] = {n}")
+        elif axes_of[n] == ("I", "J", "K"):
+            # back to the (I, J, K) output block, one transpose per I row
+            kb.line("for _i in range(ni):")
+            kb.push()
+            kb.line(f"{n}_out_ref[_i, :, :nk] = {n}[:, _oi_{n} + _i, _oj_{n}:_oj_{n} + nj].T")
+            kb.pop()
+        elif n in inout_api:
+            kb.line(f"{n}_out_ref[...] = _s_{n}[_H:_H + ni, _H:_H + nj]")
 
     # ---------------- static schedule / VMEM metadata ----------------
     schedule = {
+        "layout": layout,
         "halo": H,
         "block_default": tuple(block),
         "dma_inputs": list(dma_inputs),
@@ -293,24 +348,27 @@ def generate_pallas_source(
     # per-tile VMEM estimate terms (kind, extra_i, extra_j, copies, itemsize):
     # "ijk" is a (bi+di, bj+dj, nk) block, "ij" a (bi+di, bj+dj) slab, "k" an
     # (nk,) vector.  copies counts the VMEM buffers and in-kernel values; the
-    # sum bounds Mosaic's scoped allocation from above (docs/pallas.md).
+    # sum bounds Mosaic's scoped allocation from above (docs/pallas.md).  A
+    # K-major kernel adds its ``_arrays`` and holds no whole-array values: an
+    # (I, J, K) window and output block count once per buffer.
     vmem_terms: List[Tuple[str, int, int, int, int]] = []
 
     def kind(name: str) -> str:
         return {("I", "J", "K"): "ijk", ("I", "J"): "ij", ("K",): "k"}[axes_of[name]]
 
     for n in dma_inputs:  # halo window + the slices loaded from it
-        vmem_terms.append((kind(n), 2 * H, 2 * H, 2, np.dtype(dtype_of[n]).itemsize))
+        copies = 1 if kmajor and kind(n) == "ijk" else 2
+        vmem_terms.append((kind(n), 2 * H, 2 * H, copies, np.dtype(dtype_of[n]).itemsize))
     for n in k_inputs:
         vmem_terms.append(("k", 0, 0, 1, np.dtype(dtype_of[n]).itemsize))
     for n in written_api:  # double-buffered output block + the in-kernel value
-        vmem_terms.append((kind(n), 0, 0, 3, np.dtype(dtype_of[n]).itemsize))
+        vmem_terms.append((kind(n), 0, 0, 2 if kmajor else 3, np.dtype(dtype_of[n]).itemsize))
     for t in impl.temporaries:
         isz = np.dtype(t.dtype).itemsize
         (ilo, ihi), (jlo, jhi), _ = impl.extent_of(t.name).as_tuple()
         if t.name in windowed:
             vmem_terms.append(("ij", ihi - ilo, jhi - jlo, windowed[t.name] + 1, isz))
-        else:
+        elif not kmajor:
             vmem_terms.append((kind(t.name), ihi - ilo, jhi - jlo, 1, isz))
 
     # ---------------- module assembly ----------------
@@ -377,6 +435,19 @@ def generate_pallas_source(
     em.line("return (bi + 2 * _H, _tiled(bj + 2 * _H, sub), _tiled(nk, 128))")
     em.pop()
     em.line()
+    if kmajor:
+        em.line("def _arrays(bi, bj, nk):")
+        em.push()
+        em.line('"""(shape, dtype) of each K-major VMEM array, (k, i, j), for a (bi, bj) tile')
+        em.line('at nk levels, in the order the kernel takes them."""')
+        em.line("return [")
+        em.push()
+        for n, shape in arrays:
+            em.line(f"({shape}, {dtype_of[n]!r}),  # {n}")
+        em.pop()
+        em.line("]")
+        em.pop()
+        em.line()
     em.line("def _vmem_bytes(bi, bj, nk):")
     em.push()
     em.line('"""Per-tile VMEM footprint estimate for (bi, bj) at nk levels, counted in')
@@ -395,10 +466,17 @@ def generate_pallas_source(
     em.pop()
     em.line("else:")
     em.push()
-    em.line("size = sub * _tiled(nk, 128)")
+    # a K-major kernel holds a K field as an (nk, 1, 1) column
+    em.line("size = nk * sub * 128" if kmajor else "size = sub * _tiled(nk, 128)")
     em.pop()
     em.line("total += copies * size * isz")
     em.pop()
+    if kmajor:
+        em.line("for shape, dtype in _arrays(bi, bj, nk):")
+        em.push()
+        em.line("rows = int(np.prod(shape[:-2])) * _tiled(shape[-2], _sublanes(dtype))")
+        em.line("total += rows * _tiled(shape[-1], 128) * np.dtype(dtype).itemsize")
+        em.pop()
     em.line("return total")
     em.pop()
     em.line()
@@ -410,6 +488,7 @@ def generate_pallas_source(
         + [f"{n}_out_ref" for n in written_api]
         + [f"_s_{n}" for n in dma_inputs]
         + (["_dma_sems"] if dma_inputs else [])
+        + [n for n, _ in arrays]
     ) + "):")
     em.pop()
     source = em.source() + kb.source()
@@ -470,6 +549,8 @@ def generate_pallas_source(
     tail.line("# one DMA semaphore per prefetched input tile")
     tail.line("scratch.append(pltpu.SemaphoreType.DMA((len(scratch),)))")
     tail.pop()
+    if kmajor:
+        tail.line("scratch += [pltpu.VMEM(shape, dtype) for shape, dtype in _arrays(bi, bj, nk)]")
     tail.line("call = pl.pallas_call(kernel, grid=(nti, ntj), in_specs=in_specs, out_specs=out_specs,")
     tail.line("                      out_shape=out_shapes, scratch_shapes=scratch, interpret=INTERPRET,")
     tail.line(f"                      name={KERNEL_PREFIX + impl.name!r})")
@@ -491,7 +572,8 @@ def generate_pallas_source(
     tail.line("oi, oj, ok = origins[n]")
     tail.line("if n in _K_FIELDS:")
     tail.push()
-    tail.line("args.append(jax.lax.dynamic_slice(arr, (ok,), (nk,)))")
+    # a K-major kernel takes a K field as an (nk, 1, 1) column
+    tail.line("args.append(jax.lax.dynamic_slice(arr, (ok,), (nk,))" + (".reshape(nk, 1, 1))" if kmajor else ")"))
     tail.line("continue")
     tail.pop()
     tail.line("# edge-pad the region so the last tile's window stays in bounds")

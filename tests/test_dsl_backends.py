@@ -69,10 +69,14 @@ def test_hdiff_all_backends():
     assert_backends_agree(results)
 
 
-def test_vadv_all_backends_and_oracle():
+@pytest.mark.parametrize("NK", [11, 80, 137])
+def test_vadv_all_backends_and_oracle(NK):
+    """The Thomas solver on every backend, at nk below and above one
+    128-lane tile (Pallas runs it K-major), on a domain the tile does not
+    divide."""
     from repro.stencils.vadv import vadv_defs
 
-    NI, NJ, NK = 6, 7, 11
+    NI, NJ = 6, 7
     rng = np.random.default_rng(3)
     a = rng.normal(size=(NI, NJ, NK)) * 0.1
     b = 2.0 + rng.random((NI, NJ, NK))

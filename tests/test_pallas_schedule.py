@@ -1,12 +1,16 @@
 """Pallas backend schedule tests: double-buffered halo DMAs, k-blocked
-sweeps (rolling plane windows), and the exported SCHEDULE metadata.
+sweeps (rolling plane windows), the K-major layout of sweep kernels, and the
+exported SCHEDULE metadata.
 
 Correctness is locked differentially: every scheduling decision must be
 bit-identical (float64) to the debug oracle on numpy, jax and pallas, at
 ``opt_level=0`` and at the default pipeline.
 """
 
+import re
+
 import numpy as np
+import pytest
 
 from repro.core import analysis, frontend, gtscript, passes, storage
 from repro.core.gtscript import FORWARD, PARALLEL, Field, computation, interval
@@ -15,6 +19,9 @@ from repro.stencils.vintg import vintg_defs
 from test_passes import run_differential
 
 NI, NJ, NK = 7, 6, 5
+#: vertical grids below (COSMO-1) and above (ECMWF IFS) one 128-lane tile;
+#: the (4, 4) test tile divides neither NI nor NJ
+LEVELS = (NK, 80, 137)
 
 
 def _rand(shape, seed=0):
@@ -86,8 +93,9 @@ def test_sweep_local_temp_written_in_two_sweeps_stays_full():
 # ---------------------------------------------------------------------------
 
 
-def test_vintg_differential_all_backends():
-    shape = (NI, NJ, NK)
+@pytest.mark.parametrize("nk", LEVELS)
+def test_vintg_differential_all_backends(nk):
+    shape = (NI, NJ, nk)
     fields = {
         "rho": (_rand(shape, seed=1) * 0.5 + 1.0, (0, 0, 0)),
         "w": (_rand(shape, seed=2) * 0.5 + 1.0, (0, 0, 0)),
@@ -95,6 +103,100 @@ def test_vintg_differential_all_backends():
         "out_up": (np.zeros(shape), (0, 0, 0)),
     }
     run_differential(vintg_defs, fields, {"decay": np.float64(0.9)}, shape)
+
+
+# ---------------------------------------------------------------------------
+# K-major sweep kernels
+# ---------------------------------------------------------------------------
+
+
+def _sweep_bodies(src):
+    """The source of every generated ``_body_*`` loop body."""
+    return re.findall(r"def _body_\d+_\d+\(.*?\n(.*?)\n\s*return ", src, re.S)
+
+
+@pytest.mark.parametrize("build", ["vadv", "vintg"])
+def test_sweep_kernels_index_k_major_planes(build):
+    """A kernel with a FORWARD/BACKWARD multi-stage holds its (I, J, K)
+    arrays K-major: a sweep level is one plane, read and stored by a leading
+    index, never a masked select or reduction over the K lanes."""
+    from repro.stencils.vadv import build_vadv
+    from repro.stencils.vintg import build_vintg
+
+    st = {"vadv": build_vadv, "vintg": build_vintg}[build]("pallas", dtype="float32")
+    src = st.generated_source
+    assert st._module.SCHEDULE["layout"] == "k_major"
+    assert "_kget" not in src
+    bodies = _sweep_bodies(src)
+    assert bodies and all("_put(" not in b for b in bodies)
+    # every (I, J, K) read and write in a sweep indexes the level first
+    assert all(re.search(r"\w\[_ok_\w+ \+ k(?: [+-] \d)?, ", b) for b in bodies)
+    # each window is transposed into its K-major array once, per I row
+    assert "[:, _i, :] = _s_" in src and "].T" in src
+
+
+def test_parallel_only_kernel_keeps_k_minor_blocks():
+    """hdiff has no sweep: its horizontal offsets stay on I (major) and J
+    (sublanes), so it keeps (bi, bj, nkp) blocks and K on lanes."""
+    from repro.stencils.hdiff import build_hdiff
+
+    st = build_hdiff("pallas", dtype="float32")
+    src = st.generated_source
+    assert st._module.SCHEDULE["layout"] == "k_minor"
+    assert "pl.BlockSpec((bi, bj, nkp), lambda i, j: (i, j, 0))" in src
+    assert "in_phi_out_ref" not in src and "out_phi_out_ref[:, :, :nk] = out_phi" in src
+    assert "_arrays" not in src and "_fit(" not in src
+
+
+def test_jax_backend_source_has_no_k_major_constructs():
+    """The jax backend shares the sweep emitter: it still carries its full
+    fields through the loop and writes them with ``_dus``."""
+    from repro.stencils.vadv import build_vadv
+
+    src = build_vadv("jax", dtype="float32").generated_source
+    assert "_dus(" in src and "(cp, dp) = _carry" in src
+    for construct in ("_fit(", "_arrays", "_kget", "_put(", "].T", "[:, _i, :]", "_ok_cp + k, "):
+        assert construct not in src, construct
+
+
+def test_k_major_sweep_reads_ij_and_k_fields():
+    """A K-major kernel reads an (I, J) field as a plane and a K field as an
+    (nk, 1, 1) column, in its PARALLEL blocks and its sweeps alike."""
+
+    def defs(
+        a: Field[np.float64],
+        sfc: Field[np.float64, gtscript.IJ],
+        prof: Field[np.float64, gtscript.K],
+        o: Field[np.float64],
+        col: Field[np.float64],
+    ):
+        with computation(PARALLEL), interval(...):
+            t = a * prof
+        with computation(FORWARD):
+            with interval(0, 1):
+                o = t + sfc
+                col = prof + 0.0
+            with interval(1, None):
+                o = t + 0.5 * o[0, 0, -1] + prof[-1]
+                col = col[0, 0, -1] + prof
+
+    rng = np.random.default_rng(10)
+    shape = (NI, NJ, NK)
+    run_differential(
+        defs,
+        {
+            "a": (rng.normal(size=shape), (0, 0, 0)),
+            "sfc": (rng.normal(size=(NI, NJ)), (0, 0)),
+            "prof": (rng.normal(size=(NK,)), (0,)),
+            "o": (rng.normal(size=shape), (0, 0, 0)),
+            "col": (np.zeros(shape), (0, 0, 0)),
+        },
+        {},
+        shape,
+    )
+    st = gtscript.stencil(backend="pallas", block=(4, 4))(defs)
+    assert st._module.SCHEDULE["layout"] == "k_major"
+    assert "prof_vmem[_ok_prof + k - 1]" in st.generated_source
 
 
 def test_vintg_generated_code_carries_planes_not_arrays():
@@ -219,7 +321,8 @@ def test_dma_deferred_schedule_differential():
     )
 
 
-def test_partially_written_outputs_preserve_caller_values():
+@pytest.mark.parametrize("nk", LEVELS)
+def test_partially_written_outputs_preserve_caller_values(nk):
     """Regression (differential fuzzer): an API output written only on some
     k-intervals, or only under a mask, must keep the caller's values on the
     unwritten planes / false lanes.  The pallas backend used to zero-init
@@ -238,7 +341,7 @@ def test_partially_written_outputs_preserve_caller_values():
                 ob = ob + 1.0  # masked write: false lanes untouched
 
     rng = np.random.default_rng(9)
-    shape = (NI, NJ, NK)
+    shape = (NI, NJ, nk)
     # nonzero initial output values are what expose the clobbering
     run_differential(
         defs,
@@ -251,8 +354,10 @@ def test_partially_written_outputs_preserve_caller_values():
         shape,
     )
     st = gtscript.stencil(backend="pallas", block=(4, 4))(defs)
-    # ob is partially written → must arrive via the inout DMA path
+    # ob is partially written → must arrive via the inout DMA path, and
+    # its K-major copy keeps the caller's values on the unwritten planes
     assert "ob" in st._module.SCHEDULE["dma_inputs"]
+    assert st._module.SCHEDULE["layout"] == "k_major"
 
 
 def test_schedule_surfaces_in_exec_info():
@@ -269,6 +374,7 @@ def test_schedule_surfaces_in_exec_info():
     info = {}
     st(**fs, decay=np.float64(0.9), domain=(NI, NJ, NK), exec_info=info)
     sched = info["schedule"]
+    assert sched["layout"] == "k_major"
     assert sched["dma_inputs"] == ["rho", "w"]
     assert sched["window_fields"] == 2 and sched["window_planes"] == 2
     assert sched["full_carry_fields"] == 2
@@ -292,7 +398,6 @@ def test_float64_pallas_kernel_fails_to_build_on_a_tpu(monkeypatch):
     float64 Pallas stencil is refused when it is built, naming the dtype;
     its float32 retyping builds and compiles instead of interpreting."""
     import jax
-    import pytest
 
     from repro.core import ir
     from repro.core.stencil import build_from_definition

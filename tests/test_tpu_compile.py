@@ -90,17 +90,19 @@ def _compile_stencil(st, sharding, domain, block):
 def _build(name):
     from repro.stencils.hdiff import build_hdiff
     from repro.stencils.vadv import build_vadv
+    from repro.stencils.vintg import build_vintg
 
-    return {"hdiff": build_hdiff, "vadv": build_vadv}[name]("pallas", dtype="float32")
+    return {"hdiff": build_hdiff, "vadv": build_vadv, "vintg": build_vintg}[name]("pallas", dtype="float32")
 
 
 @pytest.mark.parametrize("nk", LEVELS)
-@pytest.mark.parametrize("name", ["hdiff", "vadv"])
+@pytest.mark.parametrize("name", ["hdiff", "vadv", "vintg"])
 def test_pallas_kernel_compiles_for_v5e(one_chip, name, nk):
     """The default tile and every tile the autotuner's VMEM filter keeps
     compile: a kept tile that Mosaic refuses is a bug in ``_vmem_bytes``.
-    The compiled custom-call is named for its stencil, so a device profile
-    tells the kernels apart."""
+    hdiff keeps K on lanes; vadv and vintg sweep K-major, transposing each
+    window in the kernel.  The compiled custom-call is named for its
+    stencil, so a device profile tells the kernels apart."""
     from repro.core import autotune
     from repro.core.codegen_pallas import KERNEL_PREFIX
 
