@@ -374,17 +374,15 @@ class DistributedEnsemble:
                 f"{member_axis!r} mesh axis"
             )
         self._cache: Dict[Any, Tuple[Callable, dict]] = {}
+        self._iter_cache: Dict[Any, Tuple[Callable, dict]] = {}
 
-    def __call__(
-        self,
-        fields: Dict[str, Any],
-        scalars: Optional[Dict[str, Any]] = None,
-        *,
-        exec_info: Optional[dict] = None,
-    ) -> Dict[str, Any]:
-        scalars = dict(scalars or {})
+    def _bind(self, fields: Dict[str, Any]):
+        """Raw arrays, which fields are member-batched, and the member-0
+        global samples that key and plan the per-member step (shapes only:
+        no member slice is materialized on the device)."""
+        import jax
+
         raw = {n: (v.data if isinstance(v, Storage) else v) for n, v in fields.items()}
-        # member-0 global samples key/compile the per-member plan
         samples = {}
         batched = {}
         for n, v in raw.items():
@@ -393,7 +391,7 @@ class DistributedEnsemble:
             else:
                 b = len(v.shape) == 4  # (N, Ni, Nj, Nk) bare-array convention
             batched[n] = b
-            samples[n] = v[0] if b else v
+            samples[n] = jax.ShapeDtypeStruct(tuple(v.shape[1:]), v.dtype) if b else v
         if not any(batched.values()):
             raise EnsembleError(
                 f"distributed ensemble {self.ensemble.name!r} called with no member-batched "
@@ -406,7 +404,17 @@ class DistributedEnsemble:
                     f"ensemble has {self.ensemble.members}"
                 )
         local, geo_key = self.dp._geometry(samples)
-        key = (geo_key, tuple(sorted(batched.items())))
+        return raw, batched, samples, local, (geo_key, tuple(sorted(batched.items())))
+
+    def __call__(
+        self,
+        fields: Dict[str, Any],
+        scalars: Optional[Dict[str, Any]] = None,
+        *,
+        exec_info: Optional[dict] = None,
+    ) -> Dict[str, Any]:
+        scalars = dict(scalars or {})
+        raw, batched, samples, local, key = self._bind(fields)
         if key not in self._cache:
             self._cache[key] = self._compile(samples, scalars, local, batched, key)
         fn, report = self._cache[key]
@@ -419,6 +427,63 @@ class DistributedEnsemble:
                 v.block_until_ready()
             exec_info["run_end_time"] = time.perf_counter()
         return out
+
+    def iterate(
+        self,
+        n: int,
+        fields: Dict[str, Any],
+        scalars: Optional[Dict[str, Any]] = None,
+        *,
+        exec_info: Optional[dict] = None,
+    ) -> Dict[str, Any]:
+        """Run ``n`` member-batched sharded steps in ONE ``shard_map``-wrapped
+        ``fori_loop`` dispatch, the halo-exchange plan applied on every
+        iteration: the same results as ``n`` calls of ``__call__``.
+
+        The member-batched fields the step uses are the loop's carried state
+        and are **donated**: their buffers become the result's, so the caller's
+        arrays are deleted and a batched :class:`Storage` is rebound to the
+        result.  Returns that state after step ``n`` as global arrays keyed by
+        field name (the output binding, for a rotation-closed program, plus
+        the other carried fields), so ``fields.update(dens.iterate(n, fields))``
+        feeds it back without a copy.  Shared fields are read, never donated.
+        """
+        scalars = dict(scalars or {})
+        raw, batched, samples, local, key = self._bind(fields)
+        ikey = (key, int(n))
+        if ikey not in self._iter_cache:
+            self._iter_cache[ikey] = self._compile_iterate(samples, scalars, local, batched, key, int(n))
+        fn, report = self._iter_cache[ikey]
+        if exec_info is not None:
+            exec_info["ensemble_report"] = dict(report)
+            exec_info["run_start_time"] = time.perf_counter()
+        with otrace.span(
+            "ensemble.mesh_iterate", category="ensemble",
+            ensemble=self.ensemble.name, members=self.ensemble.members, steps=int(n),
+        ):
+            final = fn(raw, scalars)
+        ProgramObject._writeback(fields, final)
+        if exec_info is not None:
+            for v in final.values():
+                v.block_until_ready()
+            exec_info["run_end_time"] = time.perf_counter()
+        return final
+
+    def _report(self, plan, batched) -> Dict[str, Any]:
+        per_shard = self.ensemble.members // self.m_size
+        return {
+            "members": self.ensemble.members,
+            "member_axis": self.member_axis,
+            "members_per_shard": per_shard,
+            "batched_fields": sorted(n for n, b in batched.items() if b),
+            # one stripe carries every local member (the vmapped ppermute)
+            "exchanges_per_step": len(plan.report["halo_plan"]["ops"]),
+            "exchange_bytes_per_step": per_shard * plan.report["exchange_bytes_per_step"],
+            "program_report": dict(plan.report),
+        }
+
+    def _spec(self, plan, name: str, is_batched: bool):
+        return self.dp._spec_for(plan, name, self.member_axis if is_batched else None)
 
     def _compile(self, samples, scalars, local, batched, plan_key):
         import jax
@@ -437,22 +502,60 @@ class DistributedEnsemble:
         def body(local_fields, scalar_vals):
             return vstep(local_fields, scalar_vals)
 
-        def spec(name: str, is_batched: bool):
-            m = self.member_axis if is_batched else None
-            return self.dp._spec_for(plan, name, m)
-
-        in_specs = ({n: spec(n, batched[n]) for n in used}, P())
-        out_specs = {o: spec(b, True) for o, b in plan.outputs.items()}
+        in_specs = ({n: self._spec(plan, n, batched[n]) for n in used}, P())
+        out_specs = {o: self._spec(plan, b, True) for o, b in plan.outputs.items()}
         shard_fn = jax.jit(shard_map(body, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs))
 
         def fn(all_fields, scalar_vals):
             return shard_fn({n: all_fields[n] for n in used}, scalar_vals)
 
-        report = {
-            "members": self.ensemble.members,
-            "member_axis": self.member_axis,
-            "members_per_shard": self.ensemble.members // self.m_size,
-            "batched_fields": sorted(n for n, b in batched.items() if b),
-            "program_report": dict(plan.report),
-        }
+        return fn, self._report(plan, batched)
+
+    def _compile_iterate(self, samples, scalars, local, batched, plan_key, n: int):
+        import jax
+        from jax import lax
+        from jax.sharding import PartitionSpec as P
+
+        from repro.stencils.distributed import shard_map
+
+        plan = self.dp._plan_for(samples, scalars, local, plan_key)
+        if plan.iterable_reason is not None:
+            raise ProgramError(
+                f"distributed ensemble {self.ensemble.name!r} cannot iterate: {plan.iterable_reason}"
+            )
+        carried = [b for b in plan.used_inputs if batched[b]]
+        shared = [b for b in plan.used_inputs if not batched[b]]
+        bad = sorted(set(plan.written_inputs) - set(carried))
+        if bad:
+            raise EnsembleError(
+                f"distributed ensemble {self.ensemble.name!r} writes {bad}, which are not member-batched"
+            )
+        run_groups = plan.run_groups
+
+        def member_steps(state, fixed, scalar_vals):
+            def step(_i, st):
+                # per-step state: written buffers update, then the output
+                # binding rebinds (rotation wins over the write)
+                new, outs = run_groups({**st, **fixed}, scalar_vals)
+                merged = {**new, **outs}
+                return {b: merged[b] for b in carried}
+
+            return lax.fori_loop(0, n, step, state)
+
+        vsteps = jax.vmap(member_steps, in_axes=(0, None, None))
+        in_specs = (
+            {b: self._spec(plan, b, True) for b in carried},
+            {b: self._spec(plan, b, False) for b in shared},
+            P(),
+        )
+        out_specs = {b: self._spec(plan, b, True) for b in carried}
+        shard_fn = jax.jit(
+            shard_map(vsteps, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs), donate_argnums=0
+        )
+
+        def fn(all_fields, scalar_vals):
+            return shard_fn({b: all_fields[b] for b in carried}, {b: all_fields[b] for b in shared}, scalar_vals)
+
+        report = self._report(plan, batched)
+        report["iterated_steps"] = n
         return fn, report

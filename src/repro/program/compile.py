@@ -742,16 +742,16 @@ class DistributedProgram:
         group_objects = pplan.group_objects
         const_scalars = pplan.const_scalars
         outputs = pplan.outputs
+        ni, nj, nk = local_domain
+        i_axis, j_axis = self.i_axis, self.j_axis
+        i_size, j_size, periodic = self.i_size, self.j_size, self.periodic
         report = {
             **pplan.base_report(),
             "backend": self.prog.backend,
             "mesh": dict(self.mesh.shape),
             "halo_plan": plan.summary(),
+            "exchange_bytes_per_step": _shipped_bytes(plan, graph.buffers, local_domain, (i_size, j_size), periodic),
         }
-
-        ni, nj, nk = local_domain
-        i_axis, j_axis = self.i_axis, self.j_axis
-        i_size, j_size, periodic = self.i_size, self.j_size, self.periodic
         group_buffers = [[b for b in g.buffers() if b not in temp_internals] for g in groups]
         buffers = graph.buffers
         group_runs = [obj._run for obj in group_objects]
@@ -767,6 +767,7 @@ class DistributedProgram:
             """One per-shard step: planned exchanges + group runs.  Returns
             ``(state, outs)`` — the updated values of every used input, and
             the output binding."""
+            import jax
             import jax.numpy as jnp
 
             scal = dict(const_scalars)
@@ -779,9 +780,11 @@ class DistributedProgram:
             depth: Dict[str, int] = {}
             for gi in range(len(groups)):
                 for op in plan.before_group(gi):
-                    padded[op.buffer] = exchange_halo_2d(
-                        vals[op.buffer], op.halo, i_axis, j_axis, i_size, j_size, periodic
-                    )
+                    # a stable name for the exchange's permutes and pads in a profile
+                    with jax.named_scope(f"halo.exchange[{op.buffer}]"):
+                        padded[op.buffer] = exchange_halo_2d(
+                            vals[op.buffer], op.halo, i_axis, j_axis, i_size, j_size, periodic
+                        )
                     depth[op.buffer] = op.halo
                 read_padded = plan.read_depth[gi]
                 gf: Dict[str, Any] = {}
@@ -807,9 +810,11 @@ class DistributedProgram:
             outs = {o: vals[b] for o, b in outputs.items()}
             return state, outs
 
+        written = {b for n in pplan.stencil_nodes for b in graph.node_writes(n)}
         return DistributedStepPlan(
             run_groups=run_groups,
             used_inputs=used_inputs,
+            written_inputs=sorted(written & set(used_inputs)),
             outputs=dict(outputs),
             buffers=buffers,
             report=report,
@@ -967,10 +972,32 @@ class DistributedStepPlan:
     everything ``shard_map`` wrappers (single-step, iterated, member-batched)
     need, with the planning done exactly once per argument geometry."""
 
-    def __init__(self, *, run_groups, used_inputs, outputs, buffers, report, iterable_reason):
+    def __init__(self, *, run_groups, used_inputs, written_inputs, outputs, buffers, report, iterable_reason):
         self.run_groups = run_groups
         self.used_inputs = list(used_inputs)
+        #: the used inputs some stencil of the step writes
+        self.written_inputs = list(written_inputs)
         self.outputs = dict(outputs)
         self.buffers = buffers
         self.report = report
         self.iterable_reason = iterable_reason
+
+
+def _shipped_bytes(plan, buffers, local_domain, mesh_sizes, periodic) -> int:
+    """Bytes one chip sends per step under the exchange ``plan``, for one
+    member, as the mean over the mesh's chips (a chip on a non-periodic edge
+    has no neighbour on that side).  Each exchange ships ``halo``-deep I
+    stripes, then J stripes that carry the I halo rows
+    (``parallel.halo.exchange_halo_2d``)."""
+    import numpy as np
+
+    from repro.parallel.halo import _perm_down, _perm_up
+
+    ni, nj, nk = local_domain
+    sends = [(len(_perm_up(n, p)) + len(_perm_down(n, p))) / n for n, p in zip(mesh_sizes, periodic)]
+    total = 0.0
+    for op in plan.exchanges:
+        bi, h = buffers[op.buffer], op.halo
+        depth = nk if "K" in bi.axes else 1
+        total += np.dtype(bi.dtype).itemsize * h * depth * (sends[0] * nj + sends[1] * (ni + 2 * h))
+    return int(round(total))

@@ -13,6 +13,8 @@ import tempfile
 import textwrap
 from pathlib import Path
 
+import pytest
+
 SRC = str(Path(__file__).resolve().parent.parent / "src")
 
 
@@ -342,3 +344,141 @@ def test_distributed_ensemble_members_times_domain_sharding():
     assert out["members"] == 4 and out["per_shard"] == 2
     assert out["inserted"] == 2  # one exchange serves ALL local members
     assert out["out_shape"] == [4, 32, 16, 6]
+
+
+# --- DistributedEnsemble.iterate -------------------------------------------------
+
+_ENS_ITERATE = """
+from jax.sharding import Mesh
+from repro.ensemble import Ensemble
+
+MESH, NMEM = {mesh!r}, {members}
+NI, NJ, NK, NT = 18, 14, 6, 5  # odd tiles on both meshes
+devs = np.array(jax.devices()[: int(np.prod(MESH))])
+mesh3 = Mesh(devs.reshape(MESH), ("ens", "data", "model"))
+mesh2 = Mesh(devs.reshape(MESH)[0], ("data", "model"))
+rng = np.random.default_rng(3)
+phi0 = rng.normal(size=(NMEM, NI, NJ, NK))
+u0 = np.full((NI, NJ, NK), 0.8)
+v0 = np.full((NI, NJ, NK), -0.4)
+u0[: NI // 2] = -0.8  # both upwind branches, across a tile edge
+v0[:, : NJ // 3] = 0.4
+
+def fresh():
+    z = np.zeros((NMEM, NI, NJ, NK))
+    return {{"phi": jnp.asarray(phi0), "u": jnp.asarray(u0), "v": jnp.asarray(v0), "adv": jnp.asarray(z),
+            "phi_star": jnp.asarray(z), "phi_new": jnp.asarray(z)}}
+"""
+
+
+def _ens_iterate_script(mesh, members, body: str) -> str:
+    return _STEP_DEFS + textwrap.dedent(_ENS_ITERATE.format(mesh=mesh, members=members)) + textwrap.dedent(body)
+
+
+ENSEMBLE_MESHES = {"1x2x2": ((1, 2, 2), 3), "2x2x1": ((2, 2, 1), 4)}
+
+
+@pytest.mark.parametrize("layout", sorted(ENSEMBLE_MESHES))
+def test_distributed_ensemble_iterate_matches_every_other_path(layout):
+    """``DistributedEnsemble.iterate(n)`` against n ``__call__`` steps and
+    per-member ``DistributedProgram.iterate(n)`` (bit for bit), and against
+    the undistributed ``Ensemble.iterate`` on the same interior in a zero
+    ring, the boundary the mesh sees (rounding level)."""
+    out = _run_subprocess(_ens_iterate_script(*ENSEMBLE_MESHES[layout], """
+        from repro.core.storage import Storage
+
+        dens = Ensemble(step, NMEM).distribute(mesh3)
+        stepped = fresh()
+        for _ in range(NT):
+            o = dens(stepped, sc)
+            stepped["phi"], stepped["phi_new"] = o["phi"], o["phi_new"]
+        fused = dens.iterate(NT, fresh(), sc)
+
+        dp = step.distribute(mesh2)
+        per_member = []
+        for m in range(NMEM):
+            f = {n: (a[m] if a.ndim == 4 else a) for n, a in fresh().items()}
+            per_member.append(np.asarray(dp.iterate(NT, f, sc)["phi"]))
+
+        H = 1
+        def ring(a):
+            p = np.zeros(a.shape[:-3] + (NI + 2 * H, NJ + 2 * H, NK))
+            p[..., H:-H, H:-H, :] = a
+            return p
+        st = {n: Storage(ring(np.asarray(a)), backend="jax",
+                         default_origin=(0, H, H, 0) if a.ndim == 4 else (H, H, 0),
+                         axes=("N", "I", "J", "K") if a.ndim == 4 else ("I", "J", "K"))
+              for n, a in fresh().items()}
+        single = np.asarray(Ensemble(step, NMEM).iterate(NT, **st, **sc)["phi"])[:, H:-H, H:-H, :]
+
+        got = np.asarray(fused["phi"])
+        print(json.dumps({
+            "keys": sorted(fused),
+            "vs_call": float(np.abs(got - np.asarray(stepped["phi"])).max()),
+            "vs_call_new": float(np.abs(np.asarray(fused["phi_new"]) - np.asarray(stepped["phi_new"])).max()),
+            "vs_program": float(np.abs(got - np.stack(per_member)).max()),
+            "vs_single": float(np.abs(got - single).max()),
+            "moved": float(np.abs(got - phi0).max()),
+            "shards": sorted({tuple(x.data.shape) for x in fused["phi"].addressable_shards}),
+            "tile": [NMEM // MESH[0], NI // MESH[1], NJ // MESH[2], NK],
+        }))
+        """))
+    assert out["keys"] == ["phi", "phi_new", "phi_star"]  # the carried state, fed back as is
+    assert out["vs_call"] == 0.0 and out["vs_call_new"] == 0.0
+    assert out["vs_program"] == 0.0
+    assert out["vs_single"] < 1e-12
+    assert out["moved"] > 1e-3  # the steps did something
+    assert out["shards"] == [out["tile"]]  # left sharded as it came
+
+
+def test_distributed_ensemble_iterate_donates_the_carried_state():
+    """The member-batched fields the loop carries are donated (their buffers
+    become the result's); shared fields and unused ones are left alone, and a
+    batched Storage is rebound to the result."""
+    out = _run_subprocess(_ens_iterate_script((1, 2, 2), 3, """
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core.storage import Storage
+
+        dens = Ensemble(step, NMEM).distribute(mesh3)
+        placed = {n: jax.device_put(a, NamedSharding(mesh3, P("ens", "data", "model", None) if a.ndim == 4
+                                                     else P("data", "model", None)))
+                  for n, a in fresh().items()}
+        given = dict(placed)
+        out = dens.iterate(2, placed, sc)
+        deleted = {n: a.is_deleted() for n, a in given.items()}
+
+        st = Storage(np.asarray(phi0), backend="jax", default_origin=(0, 0, 0, 0), axes=("N", "I", "J", "K"))
+        f = {**fresh(), "phi": st}
+        res = dens.iterate(2, f, sc)
+        print(json.dumps({"deleted": deleted, "rebound": st.data is res["phi"],
+                          "same_answer": bool(np.array_equal(np.asarray(res["phi"]), np.asarray(out["phi"])))}))
+        """))
+    assert out["deleted"] == {"phi": True, "phi_star": True, "phi_new": True,
+                              "u": False, "v": False, "adv": False}
+    assert out["rebound"] and out["same_answer"]
+
+
+@pytest.mark.parametrize("layout", sorted(ENSEMBLE_MESHES))
+def test_distributed_ensemble_report_counts_exchanges_and_bytes(layout):
+    """The report carries the exchanges per step and the bytes a chip ships
+    per step: one-point I stripes, then J stripes that carry the I halo, for
+    every local member in one stripe, on a mesh with no wrap-around."""
+    mesh, members = ENSEMBLE_MESHES[layout]
+    out = _run_subprocess(_ens_iterate_script(mesh, members, """
+        dens = Ensemble(step, NMEM).distribute(mesh3)
+        info, iinfo = {}, {}
+        dens(fresh(), sc, exec_info=info)
+        dens.iterate(3, fresh(), sc, exec_info=iinfo)
+        r, ri = info["ensemble_report"], iinfo["ensemble_report"]
+        print(json.dumps({k: r[k] for k in ("exchanges_per_step", "exchange_bytes_per_step", "members_per_shard")}
+                         | {"program": r["program_report"]["exchange_bytes_per_step"],
+                            "iterated": ri["iterated_steps"], "same": ri["exchange_bytes_per_step"]}))
+        """))
+    # float64, halo 1, two exchanges (phi, phi_star) a step; local tiles 9 x 7
+    # on (1, 2, 2), each chip with one neighbour in I and one in J; 9 x 14 on
+    # (2, 2, 1), with one in I and none in J
+    per_member = {"1x2x2": 2 * 8 * 6 * (7 + (9 + 2)), "2x2x1": 2 * 8 * 6 * 14}[layout]
+    assert out["exchanges_per_step"] == 2 and out["program"] == per_member
+    assert out["members_per_shard"] == {"1x2x2": 3, "2x2x1": 2}[layout]
+    assert out["exchange_bytes_per_step"] == out["same"] == out["members_per_shard"] * per_member
+    assert out["iterated"] == 3

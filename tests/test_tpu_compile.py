@@ -198,3 +198,38 @@ def test_distributed_program_compiles_for_v5e_mesh(topo, one_chip):
     whole = held(_single_chip_compile(MESH_DOMAIN, one_chip))
     ratio = held(compiled) / whole
     assert 0.2 <= ratio <= 0.35, ratio
+
+
+def test_distributed_ensemble_iterate_fits_a_v5e_host(topo):
+    """``DistributedEnsemble.iterate`` of the forecast step for COSMO-1E's 11
+    members on the whole COSMO-1 grid over the 2x2 mesh of a v5e host: the
+    carried state is donated (aliased to the result), the exchanges are
+    collective-permutes, and each chip's share fits the 15.75 GiB that XLA
+    may use."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.ensemble import Ensemble
+    from repro.stencils.forecast import DEFAULT_SCALARS
+
+    members, domain = 11, (1158, 774, 80)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 2, 2), ("ens", "data", "model"))
+    batched = NamedSharding(mesh, P("ens", "data", "model", None))
+    shared = NamedSharding(mesh, P("data", "model", None))
+    dens = Ensemble(_forecast_program(domain), members).distribute(mesh)
+    state = {n: jax.ShapeDtypeStruct((members,) + domain, jnp.float64, sharding=batched)
+             for n in ("phi", "phi_star", "phi_new")}
+    winds = {n: jax.ShapeDtypeStruct(domain, jnp.float64, sharding=shared) for n in ("u", "v", "adv")}
+    scalars = {n: np.float64(v) for n, v in DEFAULT_SCALARS.items()}
+    _raw, batched_fields, samples, local, key = dens._bind({**state, **winds})
+    fn, report = dens._compile_iterate(samples, scalars, local, batched_fields, key, 4)
+    assert report["exchanges_per_step"] == 2 and report["members_per_shard"] == members
+    scal = {n: jax.ShapeDtypeStruct((), jnp.float64, sharding=NamedSharding(mesh, P())) for n in scalars}
+    compiled = jax.jit(lambda s, w, c: fn({**s, **w}, c), donate_argnums=0).lower(
+        state, {n: winds[n] for n in ("u", "v")}, scal).compile()
+    assert "collective-permute" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    tile = members * (domain[0] // 2) * (domain[1] // 2) * domain[2] * 8
+    assert mem.alias_size_in_bytes >= 3 * tile  # phi, phi_star and phi_new
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
