@@ -34,6 +34,7 @@ import numpy as np
 from repro.obs import trace as otrace
 
 from . import caching
+from .codegen_pallas import VMEM_LIMIT_BYTES
 
 # (BI, BJ) candidates: sublane multiples × the 128-lane TPU vector width.
 # Clamped to the domain (and deduplicated) before timing.
@@ -60,11 +61,11 @@ DEFAULT_NUMPY_CANDIDATES: Tuple[Tuple[int, int], ...] = (
 def _is_numpy_module(module) -> bool:
     return getattr(module, "_BACKEND", None) == "numpy"
 
-# Mosaic's default scoped-VMEM limit per kernel (16 MiB on a v5e).  The
-# generated ``_vmem_bytes`` estimates from above what the compiler allocates
-# (checked against it in tests/test_tpu_compile.py), so a tile under this
-# budget compiles.
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+# Mosaic's default scoped-VMEM limit per kernel.  The generated
+# ``_vmem_bytes`` estimates from above what the compiler allocates (checked
+# against it in tests/test_tpu_compile.py), so a tile under this budget
+# compiles.
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES
 
 _lock = threading.Lock()
 _memory: Dict[Tuple[str, str, str], Dict[str, Any]] = {}

@@ -3,14 +3,18 @@
 TPU adaptation of the GridTools GPU schedule (see DESIGN.md §2):
 
 * The horizontal (i, j) plane is tiled over a 2-D Pallas grid; each grid cell
-  DMAs its *tile + halo* from HBM (inputs live in ``ANY`` memory space) into
-  VMEM scratch with ``pltpu.make_async_copy`` — TPU blocks cannot overlap, so
-  the CUDA shared-memory halo load becomes an explicit strided DMA.
-* **Software-prefetched halo DMAs**: every input tile's copy is issued up
-  front on its own semaphore, and the ``wait`` is deferred to the first
-  multi-stage that touches the field — inputs consumed by later multi-stages
-  stream in *while earlier multi-stages compute* instead of serializing
-  behind a start-all/wait-all barrier.
+  reads its *tile + halo* window of every input.  An (I, J, K) input is the
+  caller's own array, and each window an element-indexed ``BlockSpec``
+  (``pl.Element`` offsets, so windows overlap; K whole; the last tile's
+  overrun as high-side element padding): Pallas double-buffers the windows
+  across grid steps, and no XLA op pads or slices the input.
+* **Software-prefetched halo DMAs** for (I, J) inputs, whose J lies on lanes
+  and so cannot take an element window at a halo offset: ``run`` edge-pads
+  their region and each grid cell copies its window from HBM (``ANY``
+  memory space) into VMEM scratch with ``pltpu.make_async_copy``, every copy
+  issued up front on its own semaphore, its ``wait`` deferred to the first
+  multi-stage that touches the field.  ``SCHEDULE["windows"]`` says which
+  path each input takes.
 * All multi-stages of the stencil execute **fused** inside one kernel while
   the tile is VMEM-resident: intermediate stages (temporaries) never touch
   HBM.  This is the GridTools fusion argument restated for the TPU memory
@@ -27,8 +31,8 @@ TPU adaptation of the GridTools GPU schedule (see DESIGN.md §2):
   sweep-local recurrence temporaries collapse to a rolling window of 2-D
   planes — which is what frees VMEM headroom for larger tiles.
 * Outputs are written back through regular non-overlapping BlockSpecs.
-* The generated module exports ``SCHEDULE`` (layout, DMA waits, carried
-  planes, window depths) and ``_vmem_bytes`` (per-tile VMEM estimate) so the
+* The generated module exports ``SCHEDULE`` (layout, input windows, DMA
+  waits, carried planes, window depths) and ``_vmem_bytes`` (per-tile VMEM estimate) so the
   autotuner (``core/autotune.py``) can filter and time ``(BI, BJ)``
   candidates; ``run`` accepts ``block=`` to override ``_BLOCK_DEFAULT``.
 * The ``pallas_call`` is named ``KERNEL_PREFIX`` + the stencil's name, so
@@ -62,6 +66,9 @@ from .gtscript import GTScriptSemanticError
 
 #: the name of every generated kernel starts with this, then the stencil's name
 KERNEL_PREFIX = "gt_"
+
+#: Mosaic's default scoped-VMEM limit per kernel (16 MiB on a v5e)
+VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 
 
 def _reads_of(impl: ir.StencilImplementation) -> Dict[str, List[Tuple[int, int, int]]]:
@@ -175,11 +182,18 @@ def generate_pallas_source(
         if axes_of[n] not in (("I", "J", "K"), ("I", "J"), ("K",)):
             raise GTScriptSemanticError(f"pallas backend: unsupported axes {axes_of[n]} for {n!r}")
 
-    # the fields that arrive via an explicit halo DMA (K fields ride whole in VMEM)
+    # the fields that arrive in a halo window (K fields ride whole in VMEM):
+    # an (I, J, K) field's window is an element-indexed block of the caller's
+    # array ("direct"); an (I, J) field keeps J on lanes, where a window at a
+    # halo offset is not a whole vector tile, so ``run`` pads its region and
+    # the kernel DMAs each window by hand ("padded")
     dma_inputs = [n for n in input_api if axes_of[n] != ("K",)]
     k_inputs = [n for n in input_api if axes_of[n] == ("K",)]
+    windows = {n: "direct" if axes_of[n] == ("I", "J", "K") else "padded" for n in dma_inputs}
+    padded = [n for n in dma_inputs if windows[n] == "padded"]
 
-    # first multi-stage that touches each DMA'd input — the wait point
+    # first multi-stage that touches each windowed input: where a padded
+    # input waits for its DMA and a direct one is bound (or transposed)
     first_use: Dict[str, int] = {}
     for mi, ms in enumerate(impl.multi_stages):
         touched = _ms_touched(ms)
@@ -222,16 +236,16 @@ def generate_pallas_source(
     kb.line("nk = _NK")
     kb.line("gi = pl.program_id(0)")
     kb.line("gj = pl.program_id(1)")
-    # issue every halo DMA up front, each on its own semaphore; waits are
-    # deferred to each field's first-use multi-stage (software prefetch)
-    # into a scratch shaped by _window(): the tile, its halo, and tiling padding
-    for i, n in enumerate(dma_inputs):
+    # where each window holds its field: _AT[n] = (li, lj, sj, sk), the
+    # tile's origin li, lj rows into its halo, sj more rows of alignment in J
+    # and sk levels in K (_build).  Issue every padded input's DMA up front,
+    # each on its own semaphore; waits are deferred to each field's first-use
+    # multi-stage (software prefetch) into a scratch shaped by _window()
+    for n in dma_inputs:
+        kb.line(f"_li_{n}, _lj_{n}, _sj_{n}, _sk_{n} = _AT[{n!r}]")
+    for i, n in enumerate(padded):
         win = f"pl.ds(gi * _BI, _s_{n}.shape[0]), pl.ds(gj * _BJ, _s_{n}.shape[1])"
-        if axes_of[n] == ("I", "J"):
-            src = f"{n}_hbm.at[{win}]"
-        else:
-            src = f"{n}_hbm.at[{win}, :]"
-        kb.line(f"_cp_{n} = pltpu.make_async_copy({src}, _s_{n}, _dma_sems.at[{i}])")
+        kb.line(f"_cp_{n} = pltpu.make_async_copy({n}_hbm.at[{win}], _s_{n}, _dma_sems.at[{i}])")
         kb.line(f"_cp_{n}.start()")
     for s in impl.scalars:
         kb.line(f"{s.name} = {s.name}_smem[0]")
@@ -290,28 +304,27 @@ def generate_pallas_source(
         for n in dma_inputs:
             if first_use[n] != mi:
                 continue
-            kb.line(f"_cp_{n}.wait()")
-            if kmajor:
-                if axes_of[n] == ("I", "J", "K"):
-                    # one (J, K) -> (K, J) transpose per I row of the window
-                    kb.line(f"for _i in range(_s_{n}.shape[0]):")
-                    kb.push()
-                    kb.line(f"{n}[:, _i, :] = _s_{n}[_i].T[:nk]")
-                    kb.pop()
-                else:
-                    # an (I, J) window is already laid out as a plane
-                    printer.refs[n] = f"_s_{n}"
-                kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (_H, _H, 0)")
-            elif n in inout_api:
+            if windows[n] == "padded":
+                kb.line(f"_cp_{n}.wait()")
+            if kmajor and axes_of[n] == ("I", "J", "K"):
+                # one (J, K) -> (K, J) transpose per I row of the window,
+                # from the rows the tile and its halo take
+                kb.line(f"for _i in range(_s_{n}.shape[0]):")
+                kb.push()
+                kb.line(f"{n}[:, _i, :] = _s_{n}[_i, _sj_{n}:_sj_{n} + {n}.shape[2], :].T[_sk_{n}:_sk_{n} + nk]")
+                kb.pop()
+                kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (_li_{n}, _lj_{n}, 0)")
+            elif n in inout_api and not kmajor:
                 if axes_of[n] == ("I", "J"):
-                    kb.line(f"{n} = _s_{n}[_H:_H + ni, _H:_H + nj]")
+                    kb.line(f"{n} = _s_{n}[_li_{n}:_li_{n} + ni, _lj_{n}:_lj_{n} + nj]")
                 else:
-                    kb.line(f"{n} = _s_{n}[_H:_H + ni, _H:_H + nj, :nk]")
+                    kb.line(f"{n} = _s_{n}[_li_{n}:_li_{n} + ni, _lj_{n} + _sj_{n}:_lj_{n} + _sj_{n} + nj, _sk_{n}:_sk_{n} + nk]")
                 kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (0, 0, 0)")
             else:
-                # read-only: every read loads its window from the scratch
+                # every read loads its slice from the window; in a K-major
+                # kernel an (I, J) window is already laid out as a plane
                 printer.refs[n] = f"_s_{n}"
-                kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (_H, _H, 0)")
+                kb.line(f"_oi_{n}, _oj_{n}, _ok_{n} = (_li_{n}, _lj_{n} + _sj_{n}, _sk_{n})")
         if ms.order == ir.IterationOrder.PARALLEL:
             emit_parallel_block(impl, printer, kb, ms, mi, functional=True)
         else:
@@ -327,13 +340,14 @@ def generate_pallas_source(
             kb.line(f"{n}_out_ref[_i, :, :nk] = {n}[:, _oi_{n} + _i, _oj_{n}:_oj_{n} + nj].T")
             kb.pop()
         elif n in inout_api:
-            kb.line(f"{n}_out_ref[...] = _s_{n}[_H:_H + ni, _H:_H + nj]")
+            kb.line(f"{n}_out_ref[...] = _s_{n}[_li_{n}:_li_{n} + ni, _lj_{n}:_lj_{n} + nj]")
 
     # ---------------- static schedule / VMEM metadata ----------------
     schedule = {
         "layout": layout,
         "halo": H,
         "block_default": tuple(block),
+        "windows": dict(sorted(windows.items())),
         "dma_inputs": list(dma_inputs),
         "dma_first_use_ms": dict(sorted(first_use.items())),
         "sweeps": {
@@ -350,15 +364,24 @@ def generate_pallas_source(
     # (nk,) vector.  copies counts the VMEM buffers and in-kernel values; the
     # sum bounds Mosaic's scoped allocation from above (docs/pallas.md).  A
     # K-major kernel adds its ``_arrays`` and holds no whole-array values: an
-    # (I, J, K) window and output block count once per buffer.
+    # (I, J, K) window and output block count once per buffer.  A direct
+    # window's terms (kind, extra_i, extra_j, itemsize) count once per
+    # pipeline buffer, with up to one sublane tile of J alignment.
     vmem_terms: List[Tuple[str, int, int, int, int]] = []
+    vmem_windows: List[Tuple[str, int, int, int]] = []
 
     def kind(name: str) -> str:
         return {("I", "J", "K"): "ijk", ("I", "J"): "ij", ("K",): "k"}[axes_of[name]]
 
     for n in dma_inputs:  # halo window + the slices loaded from it
-        copies = 1 if kmajor and kind(n) == "ijk" else 2
-        vmem_terms.append((kind(n), 2 * H, 2 * H, copies, np.dtype(dtype_of[n]).itemsize))
+        isz = np.dtype(dtype_of[n]).itemsize
+        if windows[n] == "padded":
+            vmem_terms.append((kind(n), 2 * H, 2 * H, 2, isz))
+            continue
+        dj = 2 * H + 8 * max(1, 4 // isz)
+        vmem_windows.append(("ijk", 2 * H, dj, isz))
+        if not kmajor:
+            vmem_terms.append(("ijk", 2 * H, dj, 1, isz))
     for n in k_inputs:
         vmem_terms.append(("k", 0, 0, 1, np.dtype(dtype_of[n]).itemsize))
     for n in written_api:  # double-buffered output block + the in-kernel value
@@ -389,10 +412,14 @@ def generate_pallas_source(
     em.line(f"_INPUT_API = {input_api!r}")
     em.line(f"_WRITTEN_API = {written_api!r}")
     em.line(f"_K_FIELDS = {k_inputs!r}")
+    em.line(f"_PADDED = {padded!r}")
     em.line(f"_AXES = {dict(sorted((n, axes_of[n]) for n in api_names))!r}")
     em.line(f"_DTYPES = {dict(sorted((n, dtype_of[n]) for n in api_names))!r}")
     em.line(f"SCHEDULE = {schedule!r}")
     em.line(f"_VMEM_TERMS = {vmem_terms!r}")
+    em.line(f"_VMEM_WINDOWS = {vmem_windows!r}")
+    em.line("# Mosaic's default scoped-VMEM limit per kernel")
+    em.line(f"_VMEM_LIMIT = {VMEM_LIMIT_BYTES}")
     # A 32-bit kernel is traced with x64 off, so every index, loop level and
     # Python constant in it is 32-bit as Mosaic requires.  A 64-bit kernel
     # keeps x64 and runs only interpreted: Mosaic has no 64-bit vector types,
@@ -423,16 +450,30 @@ def generate_pallas_source(
     em.line("return 8 * max(1, 4 // np.dtype(dtype).itemsize)")
     em.pop()
     em.line()
-    em.line("def _window(n, bi, bj, nk):")
+    em.line("def _window(n, bi, bj, nk, sj=0):")
     em.push()
     em.line('"""Shape of input n\'s VMEM halo window for a (bi, bj) tile: the tile and its')
-    em.line('halo, the two minor axes rounded up to the vector tiling Mosaic slices by."""')
+    em.line("halo, J rounded up to the sublane tiling Mosaic slices by.  An (I, J) window")
+    em.line("rounds I to sublanes and J to lanes; an (I, J, K) window starts sj rows early")
+    em.line('in J, to an aligned row, and takes all nk levels of its array."""')
     em.line("sub = _sublanes(_DTYPES[n])")
     em.line("if _AXES[n] == ('I', 'J'):")
     em.push()
     em.line("return (_tiled(bi + 2 * _H, sub), _tiled(bj + 2 * _H, 128))")
     em.pop()
-    em.line("return (bi + 2 * _H, _tiled(bj + 2 * _H, sub), _tiled(nk, 128))")
+    em.line("return (bi + 2 * _H, _tiled(sj + _tiled(bj + 2 * _H, sub), sub), nk)")
+    em.pop()
+    em.line()
+    em.line("def _element_map(bi, bj, i0, j0, aligned):")
+    em.push()
+    em.line('"""Index map of a direct window: the element offsets of tile (i, j)\'s window,')
+    em.line('its J offset a multiple of the sublane count where every tile\'s is."""')
+    em.line("def index(i, j):")
+    em.push()
+    em.line("jo = j * bj + j0")
+    em.line("return (i * bi + i0, pl.multiple_of(jo, aligned) if aligned else jo, 0)")
+    em.pop()
+    em.line("return index")
     em.pop()
     em.line()
     if kmajor:
@@ -448,13 +489,10 @@ def generate_pallas_source(
         em.line("]")
         em.pop()
         em.line()
-    em.line("def _vmem_bytes(bi, bj, nk):")
+    em.line("def _tile_bytes(kind, di, dj, isz, bi, bj, nk):")
     em.push()
-    em.line('"""Per-tile VMEM footprint estimate for (bi, bj) at nk levels, counted in')
-    em.line('whole (sublane, lane) vector tiles as Mosaic lays arrays out."""')
-    em.line("total = 0")
-    em.line("for kind, di, dj, copies, isz in _VMEM_TERMS:")
-    em.push()
+    em.line('"""Bytes of one VMEM array of an estimate term, in whole (sublane, lane)')
+    em.line('vector tiles as Mosaic lays arrays out."""')
     em.line("sub = 8 * max(1, 4 // isz)")
     em.line("if kind == 'ijk':")
     em.push()
@@ -469,7 +507,21 @@ def generate_pallas_source(
     # a K-major kernel holds a K field as an (nk, 1, 1) column
     em.line("size = nk * sub * 128" if kmajor else "size = sub * _tiled(nk, 128)")
     em.pop()
-    em.line("total += copies * size * isz")
+    em.line("return size * isz")
+    em.pop()
+    em.line()
+    em.line("def _vmem_bytes(bi, bj, nk, buffers=None):")
+    em.push()
+    em.line('"""Per-tile VMEM footprint estimate for (bi, bj) at nk levels, with each')
+    em.line('direct window held in `buffers` pipeline buffers (default: _buffers)."""')
+    em.line("if buffers is None:")
+    em.push()
+    em.line("buffers = _buffers(bi, bj, nk)")
+    em.pop()
+    em.line("total = buffers * sum(_tile_bytes(*t, bi, bj, nk) for t in _VMEM_WINDOWS)")
+    em.line("for kind, di, dj, copies, isz in _VMEM_TERMS:")
+    em.push()
+    em.line("total += copies * _tile_bytes(kind, di, dj, isz, bi, bj, nk)")
     em.pop()
     if kmajor:
         em.line("for shape, dtype in _arrays(bi, bj, nk):")
@@ -480,14 +532,27 @@ def generate_pallas_source(
     em.line("return total")
     em.pop()
     em.line()
-    em.line("def _make_kernel(_BI, _BJ, _NK):")
+    em.line("def _buffers(bi, bj, nk):")
+    em.push()
+    em.line('"""Pipeline buffers of each direct window: two, so that the next tile\'s windows')
+    em.line('stream in while this tile computes, unless two overrun the scoped VMEM limit."""')
+    em.line("return 2 if _vmem_bytes(bi, bj, nk, 2) <= _VMEM_LIMIT else 1")
+    em.pop()
+    em.line()
+
+    def input_ref(n: str) -> str:
+        if axes_of[n] == ("K",):
+            return f"{n}_vmem"
+        return f"{n}_hbm" if windows[n] == "padded" else f"_s_{n}"
+
+    em.line("def _make_kernel(_BI, _BJ, _NK, _AT):")
     em.push()
     em.line("def _kernel(" + ", ".join(
         [f"{s.name}_smem" for s in impl.scalars]
-        + [f"{n}_vmem" if axes_of[n] == ("K",) else f"{n}_hbm" for n in input_api]
+        + [input_ref(n) for n in input_api]
         + [f"{n}_out_ref" for n in written_api]
-        + [f"_s_{n}" for n in dma_inputs]
-        + (["_dma_sems"] if dma_inputs else [])
+        + [f"_s_{n}" for n in padded]
+        + (["_dma_sems"] if padded else [])
         + [n for n, _ in arrays]
     ) + "):")
     em.pop()
@@ -499,31 +564,53 @@ def generate_pallas_source(
     tail.pop()
     tail.line()
     tail.line("@functools.lru_cache(maxsize=None)")
-    tail.line("def _build(domain, block):")
+    tail.line("def _build(domain, block, geometry):")
     tail.push()
+    tail.line('"""The jitted call for a domain, a tile and the inputs\' geometry: the')
+    tail.line('(shape, origin) of each array of _INPUT_API."""')
     tail.line("ni, nj, nk = domain")
     tail.line("bi = min(block[0], ni)")
     tail.line("bj = min(block[1], nj)")
     tail.line("nti = -(-ni // bi)")
     tail.line("ntj = -(-nj // bj)")
     tail.line("nkp = _tiled(nk, 128)")
-    tail.line("kernel = _make_kernel(bi, bj, nk)")
     tail.line("in_specs = []")
     tail.line("for s in _SCALARS:")
     tail.push()
     tail.line("in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))")
     tail.pop()
-    tail.line("for n in _INPUT_API:")
+    tail.line("buffers = _buffers(bi, bj, nk)")
+    tail.line("at = {}")
+    tail.line("for n, (shape, (oi, oj, ok)) in zip(_INPUT_API, geometry):")
     tail.push()
     tail.line("if n in _K_FIELDS:")
     tail.push()
     tail.line("in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))")
+    tail.line("continue")
     tail.pop()
-    tail.line("else:")
+    tail.line("# the window reaches up to _H rows below the tile, never below the array")
+    tail.line("li, lj = min(_H, oi), min(_H, oj)")
+    tail.line("if n in _PADDED:")
     tail.push()
+    tail.line("at[n] = (li, lj, 0, 0)")
     tail.line("in_specs.append(pl.BlockSpec(memory_space=pl.ANY))")
+    tail.line("continue")
     tail.pop()
+    tail.line("# J starts at the aligned row below: Mosaic copies whole sublane tiles")
+    tail.line("sub = _sublanes(_DTYPES[n])")
+    tail.line("sj = (oj - lj) % sub")
+    tail.line("i0, j0 = oi - li, oj - lj - sj")
+    tail.line("win = _window(n, bi, bj, shape[2], sj)")
+    tail.line("# the last tile's overrun is padded in the kernel: it feeds only points")
+    tail.line("# outside the domain, which are never written back")
+    tail.line("hi = (max(0, (nti - 1) * bi + i0 + win[0] - shape[0]), max(0, (ntj - 1) * bj + j0 + win[1] - shape[1]))")
+    tail.line("at[n] = (li, lj, sj, ok)")
+    tail.line("dims = (pl.Element(win[0], (0, hi[0])), pl.Element(win[1], (0, hi[1])), pl.Element(win[2]))")
+    tail.line("aligned = sub if bj % sub == 0 or ntj == 1 else 0")
+    tail.line("mode = None if buffers == 2 else pl.Buffered(buffers)")
+    tail.line("in_specs.append(pl.BlockSpec(dims, _element_map(bi, bj, i0, j0, aligned), pipeline_mode=mode))")
     tail.pop()
+    tail.line("kernel = _make_kernel(bi, bj, nk, at)")
     tail.line("out_specs = []")
     tail.line("out_shapes = []")
     tail.line("for n in _WRITTEN_API:")
@@ -543,7 +630,7 @@ def generate_pallas_source(
     tail.line("raise NotImplementedError('K-field outputs in pallas backend')")
     tail.pop()
     tail.pop()
-    tail.line("scratch = [pltpu.VMEM(_window(n, bi, bj, nk), _DTYPES[n]) for n in _INPUT_API if n not in _K_FIELDS]")
+    tail.line("scratch = [pltpu.VMEM(_window(n, bi, bj, nk), _DTYPES[n]) for n in _PADDED]")
     tail.line("if scratch:")
     tail.push()
     tail.line("# one DMA semaphore per prefetched input tile")
@@ -560,7 +647,8 @@ def generate_pallas_source(
     tail.line("def run(fields, scalars, domain, origins, block=None):")
     tail.push()
     tail.line("ni, nj, nk = domain")
-    tail.line("call, (bi, bj, nti, ntj) = _build(tuple(domain), tuple(block or _BLOCK_DEFAULT))")
+    tail.line("geometry = tuple((tuple(fields[n].shape), tuple(origins[n])) for n in _INPUT_API)")
+    tail.line("call, (bi, bj, nti, ntj) = _build(tuple(domain), tuple(block or _BLOCK_DEFAULT), geometry)")
     tail.line("args = []")
     tail.line("for s in _SCALARS:")
     tail.push()
@@ -576,19 +664,17 @@ def generate_pallas_source(
     tail.line("args.append(jax.lax.dynamic_slice(arr, (ok,), (nk,))" + (".reshape(nk, 1, 1))" if kmajor else ")"))
     tail.line("continue")
     tail.pop()
-    tail.line("# edge-pad the region so the last tile's window stays in bounds")
+    tail.line("if n not in _PADDED:")
+    tail.push()
+    tail.line("args.append(arr)  # each tile's window is an element block of the array itself")
+    tail.line("continue")
+    tail.pop()
+    tail.line("# edge-pad the region so every tile's window stays in bounds")
+    tail.line("li, lj = min(_H, oi), min(_H, oj)")
     tail.line("win = _window(n, bi, bj, nk)")
-    tail.line("pads = [(0, (nti - 1) * bi + win[0] - ni - 2 * _H), (0, (ntj - 1) * bj + win[1] - nj - 2 * _H)]")
-    tail.line("if _AXES[n] == ('I', 'J'):")
-    tail.push()
-    tail.line("region = arr[oi - _H:oi + ni + _H, oj - _H:oj + nj + _H]")
-    tail.pop()
-    tail.line("else:")
-    tail.push()
-    tail.line("region = arr[oi - _H:oi + ni + _H, oj - _H:oj + nj + _H, ok:ok + nk]")
-    tail.line("pads.append((0, win[2] - nk))")
-    tail.pop()
-    tail.line("args.append(jnp.pad(region, pads, mode='edge'))")
+    tail.line("size = ((nti - 1) * bi + win[0], (ntj - 1) * bj + win[1])")
+    tail.line("region = arr[oi - li:oi - li + size[0], oj - lj:oj - lj + size[1]]")
+    tail.line("args.append(jnp.pad(region, [(0, size[0] - region.shape[0]), (0, size[1] - region.shape[1])], mode='edge'))")
     tail.pop()
     tail.line("with jax.enable_x64(_X64):")
     tail.push()

@@ -300,6 +300,8 @@ class StencilObject:
                 block, autotune_record = self._resolve_block(
                     domain, [(n, tuple(v.shape)) for n, v in raw_fields.items()]
                 )
+                # inputs whose halo windows the kernel DMAs from a padded copy
+                sp.set("padded_inputs", len(self._module._PADDED))
                 if exec_info is not None:
                     exec_info["schedule"] = getattr(self._module, "SCHEDULE", None)
                     exec_info["interpret"] = self.interpreted

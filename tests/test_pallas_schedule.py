@@ -267,10 +267,10 @@ def test_windowed_temp_with_horizontal_halo():
 # ---------------------------------------------------------------------------
 
 
-def _two_ms_defs(a: Field[np.float64], b: Field[np.float64],
+def _two_ms_defs(a: Field[np.float64, gtscript.IJ], b: Field[np.float64, gtscript.IJ],
                  o1: Field[np.float64], o2: Field[np.float64]):
     with computation(PARALLEL), interval(...):
-        t = (a[1, 0, 0] + a[-1, 0, 0]) * 0.5
+        t = (a[1, 0] + a[-1, 0]) * 0.5
         o1 = t + a
     with computation(FORWARD):
         with interval(0, 1):
@@ -299,20 +299,25 @@ def test_dma_waits_deferred_to_first_use():
     assert i_ms0 < src.index("_cp_a.wait()") < i_ms1
     assert src.index("_cp_b.wait()") > i_ms1
     sched = st._module.SCHEDULE
-    # o1/o2 are written-and-read (inout): their tiles DMA in too, each
-    # waiting at its own first-touching multi-stage
+    # (I, J) inputs take the hand-DMA'd padded windows; o1/o2 are
+    # written-and-read (inout) (I, J, K) fields, whose element windows the
+    # pipeline copies in: each binds at its own first-touching multi-stage
+    assert sched["windows"] == {"a": "padded", "b": "padded", "o1": "direct", "o2": "direct"}
+    assert "_cp_o1" not in src and "_cp_o2" not in src
     assert sched["dma_first_use_ms"] == {"a": 0, "b": 1, "o1": 0, "o2": 1}
+    assert i_ms0 < src.index("o1[:, _i, :] = _s_o1[") < i_ms1
+    assert src.index("o2[:, _i, :] = _s_o2[") > i_ms1
 
 
 def test_dma_deferred_schedule_differential():
     H = 1
     shape = (NI + 2 * H, NJ + 2 * H, NK)
-    a, b = _rand(shape, seed=5), _rand(shape, seed=6)
+    a, b = _rand(shape[:2], seed=5), _rand(shape[:2], seed=6)
     run_differential(
         _two_ms_defs,
         {
-            "a": (a, (H, H, 0)),
-            "b": (b, (H, H, 0)),
+            "a": (a, (H, H)),
+            "b": (b, (H, H)),
             "o1": (np.zeros(shape), (H, H, 0)),
             "o2": (np.zeros(shape), (H, H, 0)),
         },

@@ -28,6 +28,11 @@ import pytest
 NIJ = 256
 LEVELS = (80, 137)
 MESH_DOMAIN = (512, 512, 80)
+#: the dycore cell's grid (COSMO-1) and storages: halo-3 fields, and vadv's
+#: coefficients a, b, c stored without a halo (bench/drivers/dycore_step.py)
+DYCORE = (1158, 774, 80)
+DYCORE_HALO = 3
+BARE = ("a", "b", "c")
 
 
 @pytest.fixture(scope="module")
@@ -69,18 +74,21 @@ def compiled_kernels(module):
         module._build.cache_clear()
 
 
-def _compile_stencil(st, sharding, domain, block):
+def _compile_stencil(st, sharding, domain, block, halo=None, bare=()):
+    """Compile ``st``'s call on storages that hold the domain in a ring of
+    ``halo`` points (default: the stencil's own), the fields in ``bare`` in
+    none."""
     import jax
     import jax.numpy as jnp
 
     mod = st._module
     ni, nj, nk = domain
-    h = mod._H
-    fields = {
-        n: jax.ShapeDtypeStruct((ni + 2 * h, nj + 2 * h, nk), jnp.dtype(mod._DTYPES[n]), sharding=sharding)
-        for n in mod._AXES
-    }
-    origins = {n: (h, h, 0) for n in mod._AXES}
+    fields, origins = {}, {}
+    for n in mod._AXES:
+        h = 0 if n in bare else mod._H if halo is None else halo
+        shape = (ni + 2 * h, nj + 2 * h, nk)
+        fields[n] = jax.ShapeDtypeStruct(shape, jnp.dtype(mod._DTYPES[n]), sharding=sharding)
+        origins[n] = (h, h, 0)
     scalars = {s: jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding) for s in mod._SCALARS}
     with compiled_kernels(mod):
         fn = jax.jit(lambda f, s: mod.run(f, s, domain, origins, block=block))
@@ -120,6 +128,27 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, name, nk):
         assert "tpu_custom_call" in text
         kernel = re.escape(KERNEL_PREFIX + st.name)
         assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(", text), f"no custom-call named {kernel}"
+
+
+@pytest.mark.parametrize("name", ["hdiff", "vadv"])
+def test_dycore_kernels_read_the_callers_arrays(one_chip, name):
+    """At the dycore cell's geometry and default tile the kernel reads every
+    input from the caller's own array: the compiled call holds no op of
+    ``run``'s edge pad (``jit(_pad)``), and the kernel's operands are the
+    storages as they are, 80 levels deep rather than padded to 128 lanes."""
+    from repro.core.codegen_pallas import KERNEL_PREFIX
+
+    st = _build(name)
+    ni, nj, nk = DYCORE
+    block = tuple(st._module._BLOCK_DEFAULT)
+    text = _compile_stencil(st, one_chip, DYCORE, block, halo=DYCORE_HALO, bare=BARE).as_text()
+    assert "jit(_pad)" not in text
+    kernel = re.escape(KERNEL_PREFIX + st.name)
+    call = re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(.*operand_layout_constraints=\{{(.*?)\}}, ", text)
+    assert call, f"no custom-call named {kernel}"
+    operands = re.findall(r"f32\[([\d,]+)\]", call.group(2))
+    storages = {f"{ni + 2 * h},{nj + 2 * h},{nk}" for h in (0, DYCORE_HALO)}
+    assert operands and set(operands) - {"1"} <= storages, operands
 
 
 def _forecast_program(domain):
