@@ -1,9 +1,9 @@
-"""repro — GT4Py-style performance-portable stencil DSL + multi-pod JAX
-training/serving framework.
+"""repro — a GT4Py-style performance-portable stencil DSL on JAX and Pallas,
+with a multi-stencil program orchestrator, member-batched ensembles and a
+batching forecast server.
 
-Weather & climate stencils (the paper's domain) use float64, so x64 is
-enabled globally; all model/kernel code states dtypes explicitly (bf16/f32)
-and is unaffected.
+Weather & climate stencils (the paper's domain) default to float64, so x64
+is enabled globally; a float32 stencil states its dtype in its fields.
 """
 
 import jax

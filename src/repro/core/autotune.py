@@ -5,9 +5,8 @@ the best ``(BI, BJ)`` tile depends on the domain, the stencil's VMEM
 footprint, and the DMA/compute balance — exactly the schedule knob the paper
 argues the toolchain (not the user) should turn.  This module times a small
 set of candidate tiles against the stencil's own generated ``run`` (on
-synthetic inputs shaped from the module's field metadata, the
-``benchmarks/run.py`` timing discipline: warmup, then best-of-N) and picks
-the fastest.
+synthetic inputs shaped from the module's field metadata: warmup, then
+best-of-N) and picks the fastest.
 
 Results are **keyed on the pass-aware cache fingerprint** from
 ``core/caching.py`` and persisted as ``<name>_<fp>.tune.json`` next to the

@@ -20,7 +20,7 @@ from typing import Any, Dict, Optional
 
 from . import ir
 
-_CACHE_VERSION = "repro-gt-4"
+_CACHE_VERSION = "repro-gt-5"
 _lock = threading.Lock()
 _memory_cache: Dict[str, ModuleType] = {}
 

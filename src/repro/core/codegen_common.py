@@ -114,6 +114,8 @@ class ArrayExprPrinter:
         # an input's VMEM halo scratch rather than an in-kernel value copy,
         # and in a K-major kernel the K columns and (I, J) outputs
         self.refs: Dict[str, str] = {}
+        # dtype of the field the statement being printed assigns
+        self.target_dtype: Optional[str] = None
 
     # -- region slices ---------------------------------------------------------
 
@@ -265,7 +267,13 @@ class ArrayExprPrinter:
                 return f"{lib}.logical_or({self.expr(e.left)}, {self.expr(e.right)})"
             return f"({self.expr(e.left)} {e.op} {self.expr(e.right)})"
         if isinstance(e, ir.TernaryOp):
-            return f"{lib}.where({self.expr(e.cond)}, {self.expr(e.true_expr)}, {self.expr(e.false_expr)})"
+            where = f"{lib}.where({self.expr(e.cond)}, {self.expr(e.true_expr)}, {self.expr(e.false_expr)})"
+            literals = isinstance(e.true_expr, ir.Literal) and isinstance(e.false_expr, ir.Literal)
+            if lib == "np" and literals and self.target_dtype:
+                # np.where makes two Python floats a float64 array, where the
+                # debug backend and jnp keep them weak: take the target's dtype
+                return f"{where}.astype('{self.target_dtype}')"
+            return where
         if isinstance(e, ir.NativeCall):
             return self._native(e)
         if isinstance(e, ir.Cast):
@@ -313,6 +321,7 @@ class ArrayStmtEmitter:
     def assign(self, stmt: ir.Assign, mask: Optional[str]) -> None:
         p = self.p
         name = stmt.target.name
+        p.target_dtype = p.dtype_of[name]
         value = p.expr(stmt.value)
         if mask is not None:
             old = p.read(ir.FieldAccess(name, (0, 0, 0)))
@@ -347,6 +356,7 @@ class ArrayStmtEmitter:
         p = self.p
         self._mask_counter += 1
         mv = f"_mask_{self._mask_counter}"
+        p.target_dtype = None
         cond = p.expr(stmt.cond)
         self.em.line(f"{mv} = {cond}")
         then_mask = mv if mask is None else f"{p.lib}.logical_and({mask}, {mv})"

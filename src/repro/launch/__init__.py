@@ -1,3 +1,2 @@
-"""Launch layer: production meshes, input specs, dry-run, the train driver,
-and the forecast-serving driver (``python -m repro.launch.serve`` — the
-CLI over ``repro.serving``, docs/serving.md)."""
+"""Launch layer: the forecast-serving driver (``python -m repro.launch.serve``,
+the CLI over ``repro.serving``, docs/serving.md)."""

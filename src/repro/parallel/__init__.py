@@ -1,20 +1,2 @@
-"""Distribution substrate: logical axis rules, sharding helpers, pipeline
-parallelism, halo exchange for distributed stencils, gradient compression."""
-
-from .sharding import (
-    LogicalAxisRules,
-    axis_rules,
-    current_rules,
-    logical_sharding,
-    logical_spec,
-    with_logical_constraint,
-)
-
-__all__ = [
-    "LogicalAxisRules",
-    "axis_rules",
-    "current_rules",
-    "logical_sharding",
-    "logical_spec",
-    "with_logical_constraint",
-]
+"""Halo exchange for distributed stencils (``halo.py``): the collective
+permutes a sharded stencil or program runs between its shards."""

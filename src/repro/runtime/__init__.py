@@ -1,8 +1,7 @@
-"""Fault-tolerant distributed runtime: train state/step, restartable loop,
-straggler watchdog, process supervision, gradient compression."""
+"""The forecast server's process supervision: the restart policy, the
+supervisor that spawns and probes the server, and the straggler watchdog
+the engine times its dispatches with (``supervise.py``)."""
 
-from .loop import TrainState, Trainer, make_train_step
-from .compression import int8_compress, int8_decompress
 from .supervise import (
     RestartPolicy,
     StragglerWatchdog,
@@ -17,11 +16,6 @@ __all__ = [
     "StragglerWatchdog",
     "Supervisor",
     "SupervisorGaveUp",
-    "TrainState",
-    "Trainer",
     "WatchdogStats",
     "http_ready",
-    "int8_compress",
-    "int8_decompress",
-    "make_train_step",
 ]

@@ -1,14 +1,11 @@
-"""Process supervision and health watchdogs, decoupled from the LM trainer.
+"""Process supervision and health watchdogs for the forecast server.
 
-The pieces the training loop (``runtime/loop.py``) grew for 1000-node runs —
-the rolling-median straggler watchdog and the restore-and-retry restart
-policy — apply just as well to a *serving* process: a forecast server must be
-spawned, probed for readiness, restarted with backoff when it dies, and given
-up on when it crash-loops.  This module owns those mechanisms; the trainer
-and the serving launcher both import from here.
+A forecast server must be spawned, probed for readiness, restarted with
+backoff when it dies, and given up on when it crash-loops; its engine times
+each dispatch against the rolling median.  This module owns those
+mechanisms; the engine and the serving launcher import from here.
 
-* :class:`StragglerWatchdog` — rolling-median step/dispatch timer (moved from
-  ``runtime.loop``, which re-exports it for compatibility).
+* :class:`StragglerWatchdog` — rolling-median step/dispatch timer.
 * :class:`RestartPolicy` — exponential backoff + crash-loop detection over a
   sliding window.
 * :class:`Supervisor` — spawn a child process, poll a readiness probe,
@@ -41,7 +38,7 @@ log = logging.getLogger("repro.runtime")
 
 
 # ---------------------------------------------------------------------------
-# straggler watchdog (moved from runtime/loop.py)
+# straggler watchdog
 # ---------------------------------------------------------------------------
 
 

@@ -2,8 +2,8 @@ import os
 import sys
 from pathlib import Path
 
-# Smoke tests / benches must see the single real CPU device (the 512-device
-# override is confined to launch/dryrun.py per the multi-pod dry-run rules).
+# Tests see the single real CPU device; multi-device tests set
+# --xla_force_host_platform_device_count in a child process.
 os.environ.setdefault("REPRO_GT_CACHE", str(Path(__file__).resolve().parent.parent / ".gt_cache"))
 
 SRC = str(Path(__file__).resolve().parent.parent / "src")

@@ -1,6 +1,6 @@
-"""Per-kernel validation: shape/dtype sweeps + hypothesis property tests,
-each kernel asserted allclose against its pure-jnp ref.py oracle
-(Pallas interpret mode on CPU)."""
+"""The DSL-generated hdiff and vadv kernels against their pure-jnp ref.py
+oracles (Pallas interpret mode on CPU), with a hypothesis property test of
+the vadv solver."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -22,122 +22,9 @@ except ImportError:  # optional dep: property tests skip, the sweeps still run
     def settings(*_a, **_k):
         return lambda fn: fn
 
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import flash_attention_ref
-from repro.kernels.rglru.ops import rglru_scan
-from repro.kernels.rglru.ref import rglru_scan_ref
-
 
 def _rand(shape, dtype=np.float32, seed=0):
     return jnp.asarray(np.random.default_rng(seed).normal(size=shape).astype(dtype))
-
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("b,s,h,kh,dh", [
-    (1, 32, 4, 4, 32),    # MHA
-    (2, 64, 8, 2, 64),    # GQA 4:1
-    (1, 48, 6, 1, 128),   # MQA, ragged seq
-    (2, 16, 4, 2, 96),    # non-128 head dim
-])
-@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
-def test_flash_attention_shapes_dtypes(b, s, h, kh, dh, dtype):
-    q = _rand((b, s, h, dh), seed=1).astype(dtype)
-    k = _rand((b, s, kh, dh), seed=2).astype(dtype)
-    v = _rand((b, s, kh, dh), seed=3).astype(dtype)
-    o = flash_attention(q, k, v, causal=True, bq=16, bk=16)
-    r = flash_attention_ref(q, k, v, causal=True)
-    tol = 2e-6 if dtype == np.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(r, np.float32),
-                               atol=tol, rtol=tol)
-
-
-def test_flash_attention_window_and_cap():
-    q, k, v = (_rand((2, 64, 4, 32), seed=i) for i in range(3))
-    o = flash_attention(q, k, v, causal=True, window=16, cap=20.0, bq=16, bk=16)
-    r = flash_attention_ref(q, k, v, causal=True, window=16, cap=20.0)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=2e-6)
-
-
-def test_flash_attention_decode_against_prefill():
-    """Decoding position t must equal row t of full prefill attention."""
-    b, s, h, kh, dh = 1, 32, 4, 2, 32
-    q = _rand((b, s, h, dh), seed=5)
-    k = _rand((b, s, kh, dh), seed=6)
-    v = _rand((b, s, kh, dh), seed=7)
-    full = flash_attention_ref(q, k, v, causal=True)
-    for t in [0, 13, 31]:
-        o = flash_attention(q[:, t:t + 1], k, v, causal=True, q_offset=t,
-                            kv_len=t + 1, bq=8, bk=16)
-        np.testing.assert_allclose(np.asarray(o[:, 0]), np.asarray(full[:, t]), atol=2e-6)
-
-
-@settings(max_examples=20, deadline=None)
-@given(
-    s=st.integers(8, 80),
-    skv=st.integers(8, 96),
-    h_and_kh=st.sampled_from([(4, 4), (4, 2), (6, 2), (8, 1)]),
-    dh=st.sampled_from([16, 32, 64]),
-    causal=st.booleans(),
-)
-def test_flash_attention_property(s, skv, h_and_kh, dh, causal):
-    h, kh = h_and_kh
-    if causal and skv < s:
-        skv = s  # causal requires kv covering q positions
-    q = _rand((1, s, h, dh), seed=s)
-    k = _rand((1, skv, kh, dh), seed=skv)
-    v = _rand((1, skv, kh, dh), seed=skv + 1)
-    o = flash_attention(q, k, v, causal=causal, bq=16, bk=32)
-    r = flash_attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=3e-6)
-    # softmax convexity: outputs lie within [min, max] of values
-    assert float(jnp.max(o)) <= float(jnp.max(v)) + 1e-4
-    assert float(jnp.min(o)) >= float(jnp.min(v)) - 1e-4
-
-
-# ---------------------------------------------------------------------------
-# rglru scan
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("b,s,d", [(1, 16, 8), (2, 64, 32), (3, 100, 48)])
-@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
-def test_rglru_shapes_dtypes(b, s, d, dtype):
-    rng = np.random.default_rng(b * 100 + s)
-    a = jnp.asarray(rng.uniform(0.5, 0.999, size=(b, s, d))).astype(dtype)
-    x = jnp.asarray(rng.normal(size=(b, s, d))).astype(dtype)
-    h0 = jnp.asarray(rng.normal(size=(b, d))).astype(dtype)
-    y = rglru_scan(a, x, h0, bb=2, bd=16, chunk=16)
-    r = rglru_scan_ref(a, x, h0)
-    tol = 5e-6 if dtype == np.float32 else 5e-2
-    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(r, np.float32),
-                               atol=tol, rtol=tol)
-
-
-@settings(max_examples=20, deadline=None)
-@given(
-    b=st.integers(1, 4),
-    s=st.integers(2, 64),
-    d=st.integers(4, 40),
-    decay=st.floats(0.0, 0.999),
-)
-def test_rglru_property(b, s, d, decay):
-    rng = np.random.default_rng(42)
-    a = jnp.full((b, s, d), decay, jnp.float32)
-    x = jnp.asarray(rng.normal(size=(b, s, d)).astype(np.float32))
-    y = rglru_scan(a, x, bb=2, bd=8, chunk=8)
-    r = rglru_scan_ref(a, x, jnp.zeros((b, d), jnp.float32))
-    np.testing.assert_allclose(np.asarray(y), np.asarray(r), atol=1e-4, rtol=1e-4)
-
-
-def test_rglru_zero_decay_is_identity():
-    """a ≡ 0 ⇒ h_t = b_t exactly."""
-    x = _rand((2, 16, 8), seed=9)
-    y = rglru_scan(jnp.zeros_like(x), x)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(x), atol=1e-7)
 
 
 # ---------------------------------------------------------------------------

@@ -373,3 +373,59 @@ def test_different_domains_split_groups_and_stay_exact():
     sc(s["phi_star"], s["adv"], s["phi_new"], dt=SCALARS["dt"], domain=small)
     assert np.array_equal(np.asarray(p["phi_new"]), np.asarray(s["phi_new"]))
     assert np.array_equal(np.asarray(p["phi_star"]), np.asarray(s["phi_star"]))
+
+
+# ---------------------------------------------------------------------------
+# the climate step's fusion, halo plan and ensemble fields on jax
+# ---------------------------------------------------------------------------
+
+
+def test_climate_step_fuses_its_five_calls_into_one_jax_group():
+    info = {}
+    _program_steps("jax", 1, exec_info=info)
+    rep = info["program_report"]
+    assert (rep["groups"], rep["fused_stencils"]) == (1, 4)
+
+
+def test_climate_step_plans_two_exchanges_a_step_against_six():
+    """The halo plan a mesh decomposition of the step would run, planned
+    from its graph with no devices: the vertical solve starts a second
+    group, and two fields cross a shard edge per step where the eager chain
+    exchanges six."""
+    from repro.program.graph import ProgramGraph
+    from repro.program.halo import plan_halo_exchanges
+    from repro.program.passes import eliminate_dead_stores, plan_groups
+
+    graph = ProgramGraph(_make_program("jax").trace(_stores("jax"), SCALARS))
+    live, _dropped = eliminate_dead_stores(graph)
+    graph.nodes = live
+    groups, markers = plan_groups(graph, live, distributed=True)
+    summary = plan_halo_exchanges(graph, groups, markers).summary()
+    assert (len(groups), summary["inserted"], summary["baseline_per_step"]) == (2, 2, 6)
+
+
+@pytest.mark.parametrize(
+    "report_key, fields",
+    [
+        ("batched_fields", ["a", "adv", "b", "c", "d", "phi", "phi_h", "phi_new", "phi_star"]),
+        ("shared_fields", ["u", "v", "w"]),
+    ],
+)
+def test_climate_ensemble_batches_the_state_and_shares_the_winds(report_key, fields):
+    """Eight members of the step: a field handed over member-batched is
+    batched, the unbatched winds are shared by every member."""
+    from repro.ensemble import Ensemble, batch, perturb
+
+    members = 8
+    stores = _stores("jax")
+    args = {}
+    for n in FIELD_NAMES:
+        if n in ("u", "v", "w"):
+            args[n] = stores[n]
+        elif n == "phi":
+            args[n] = perturb(stores[n], members, seed=0, amplitude=1e-3)
+        else:
+            args[n] = batch.broadcast(stores[n], members, backend="jax")
+    info = {}
+    Ensemble(_make_program("jax"), members)(*[args[n] for n in FIELD_NAMES], **SCALARS, exec_info=info)
+    assert sorted(info["ensemble_report"][report_key]) == fields

@@ -17,7 +17,7 @@ import repro  # noqa: F401
 from repro.core import caching
 from repro.core.storage import Storage
 from repro.ensemble import EnsembleError, batch
-from repro.serving import RequestSpec, ServingEngine, ServingError, drive_engine
+from repro.serving import FaultInjector, RequestSpec, ServingEngine, ServingError, drive_engine
 from repro.serving.engine import _segment_plan, tuned_member_counts
 from repro.stencils.forecast import (
     DEFAULT_SCALARS,
@@ -169,6 +169,63 @@ def test_load_generator_smoke(step, templates, engine):
         assert np.abs(res.final_fields["phi"] - ref).max() == 0.0
     st = engine.stats()
     assert st["requests"] == n and st["steps_streamed"] == 2 * n
+
+
+def _two_rounds(step, templates, **engine_kw):
+    """Eight 8-step requests streamed every 2, sent twice to one warm engine
+    with member counts up to 8: the two reports and the engine's stats."""
+    fields, scalars = templates
+
+    def specs():
+        return [
+            RequestSpec("serve_step", {"phi": request_state(DOM, seed=i + 1)}, steps=8, stream_every=2)
+            for i in range(8)
+        ]
+
+    async def go():
+        eng = ServingEngine(window_ms=10.0, retry_backoff_ms=2.0, **engine_kw)
+        eng.register(
+            step,
+            fields=fields,
+            scalars=scalars,
+            request_fields=("phi",),
+            member_counts=(1, 2, 4, 8),
+            warm=True,
+            warm_chunk=2,
+        )
+        async with eng:
+            first = await drive_engine(eng, specs(), keep_fields="none")
+            second = await drive_engine(eng, specs(), keep_fields="none")
+        return (first, second), eng.stats()
+
+    return asyncio.run(go())
+
+
+@pytest.fixture(scope="module")
+def two_rounds(step, templates):
+    return _two_rounds(step, templates, faults=FaultInjector())
+
+
+def test_eight_requests_ride_one_full_batch_a_round(two_rounds):
+    reports, stats = two_rounds
+    assert [r.mean_occupancy for r in reports] == [1.0, 1.0]
+    assert (stats["requests"], stats["batches"]) == (16, 2)
+
+
+def test_eight_requests_stream_every_second_step(two_rounds):
+    reports, stats = two_rounds
+    assert all(r.all_in_order for r in reports)
+    assert stats["steps_streamed"] == 64  # 2 rounds x 8 requests x 4 stream points
+
+
+def test_seeded_dispatch_faults_are_all_recovered(step, templates):
+    """A tenth of the dispatches fail (seeded): retries recover every
+    request without a bisect."""
+    reports, stats = _two_rounds(
+        step, templates, faults=FaultInjector(sites=("dispatch",), rate=0.10, seed=42), retry_attempts=6
+    )
+    assert [r.recovered_rate for r in reports] == [1.0, 1.0]
+    assert (stats["retries"], stats["bisects"]) == (3, 0)
 
 
 # ---------------------------------------------------------------------------

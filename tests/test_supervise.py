@@ -336,14 +336,11 @@ def test_worker_death_black_box_identifies_poison_request(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the watchdog still behaves after its move to runtime.supervise
+# the straggler watchdog
 # ---------------------------------------------------------------------------
 
 
 def test_watchdog_flags_stragglers_and_reexports():
-    from repro.runtime.loop import StragglerWatchdog as FromLoop
-
-    assert FromLoop is StragglerWatchdog  # compat re-export intact
     flagged = []
     wd = StragglerWatchdog(factor=3.0, on_straggler=lambda s, dt, med: flagged.append(s))
     for i in range(10):
